@@ -10,7 +10,8 @@ reduced models; the full-scale reproduction path is launch/train.py on real
 hardware):
   table1_microllama   adaptive(eta sweep) vs constant vs stagewise, DDP-Norm
   table2_tinyllama    same schemes under FSDP-Norm on a 4-worker mesh
-                      (subprocess with 4 host devices, like the paper's 4 GPUs)
+                      (CPU subprocess with 4 host devices, like the paper's
+                      4 GPUs)
   table3_openllama    adaptive vs constant vs stagewise, ACCUM-NORM variant
 System benches:
   serve               continuous-batching serving tier under bursty
@@ -41,6 +42,21 @@ import jax.numpy as jnp
 def _row(name, us_per_call, **derived):
     payload = ";".join(f"{k}={v}" for k, v in derived.items())
     print(f"{name},{us_per_call:.1f},{payload}", flush=True)
+
+
+# names of the benches whose child process failed: main() exits non-zero
+FAILED: list = []
+
+
+def _cpu_child_env() -> dict:
+    """Environment of a bench's child process.  The children are CPU checks
+    (coordination, the 4-worker schedule): JAX_PLATFORMS=cpu keeps them off
+    an accelerator this process may already hold."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    return env
 
 
 # Per-step perf trajectory, written to --json-out (BENCH_step.json) so the
@@ -122,14 +138,13 @@ for scheme, eta in (("adaptive", 0.08), ("constant", None), ("stagewise", None))
     s["us"] = (time.time()-t0)/max(s["steps"],1)*1e6
     print("ROW", scheme, eta, json.dumps(s))
 """
-    env = dict(os.environ)
+    env = _cpu_child_env()
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
     res = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env, timeout=1800)
     if res.returncode != 0:
         _row("table2_tinyllama/FAILED", 0, err=res.stderr[-200:].replace("\n", " "))
+        FAILED.append("table2_tinyllama")
         return
     for line in res.stdout.splitlines():
         if line.startswith("ROW"):
@@ -196,14 +211,14 @@ def bench_engine_cache(steps):
 _COORD_RANK_CODE = """
 import json, sys
 from repro.launch.train import TrainJob, run_training
-rank, coord_dir, cache_dir = int(sys.argv[1]), sys.argv[2], sys.argv[3]
+rank, coord_dir = int(sys.argv[1]), sys.argv[2]
 job = TrainJob(arch="llama3.2-1b", schedule="stagewise",
                stages=((0.5, 4), (0.5, 8)), steps=12, total_samples=48,
                seq_len=16, base_global_batch=4, max_global_batch=8,
                base_micro_batch=2, max_micro_batch=2, base_accum=2,
                step_impl="accum_norm", eval_every=0, aot_warmup=True,
                coord="file", coord_dir=coord_dir, coord_rank=rank,
-               coord_world=2, coord_timeout=120.0, compile_cache=cache_dir)
+               coord_world=2, coord_timeout=120.0)
 h = run_training(job)
 print("ENG", json.dumps(h["engine"]))
 """
@@ -214,16 +229,15 @@ def _bench_coordination():
     multi-host half of the engine story.  Reports per-rank barrier crossings
     and wait time (the coordination overhead a fleet pays per rung
     transition) plus warmups/hit-rate proving the post-increase step was a
-    cache hit on both hosts."""
+    cache hit on both hosts.  Both ranks share the one persistent compile
+    cache (`coordination.compile_cache_dir`)."""
     import subprocess
     import tempfile
     with tempfile.TemporaryDirectory() as tmp:
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "src")
-        coord, cache = os.path.join(tmp, "coord"), os.path.join(tmp, "cc")
+        env = _cpu_child_env()
+        coord = os.path.join(tmp, "coord")
         procs = [subprocess.Popen(
-            [sys.executable, "-c", _COORD_RANK_CODE, str(r), coord, cache],
+            [sys.executable, "-c", _COORD_RANK_CODE, str(r), coord],
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
             env=env) for r in range(2)]
         out = {}
@@ -233,6 +247,7 @@ def _bench_coordination():
                 if p.returncode != 0:
                     _row("engine_coord/FAILED", 0,
                          err=stderr[-200:].replace("\n", " "))
+                    FAILED.append("engine_coord")
                     return
                 eng = json.loads(next(l for l in stdout.splitlines()
                                       if l.startswith("ENG")).split(" ", 1)[1])
@@ -413,7 +428,7 @@ def _bench_step_per_bucket(nsteps):
     from repro.core.schedule import bucket_ladder
     from repro.distributed.flatbuf import FlatLayout
 
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.configs import get_smoke_config
     from repro.data.pipeline import MarkovTokens, make_batch
     from repro.distributed.train_step import make_fsdp_norm_step
@@ -745,6 +760,8 @@ def main(argv=None) -> None:
     only = set(args.only.split(",")) if args.only else None
     if only and (unknown := only - set(BENCHES)):
         p.error(f"unknown bench(es): {sorted(unknown)}")
+    from repro.distributed.coordination import enable_persistent_cache
+    enable_persistent_cache()
     print("name,us_per_call,derived")
     for name, fn in BENCHES.items():
         if only and name not in only:
@@ -767,6 +784,8 @@ def main(argv=None) -> None:
         from benchmarks.perf_gate import run_gate
         if run_gate(args.json_out, args.baseline, args.gate_mult):
             raise SystemExit(1)
+    if FAILED:
+        raise SystemExit(f"child process failed in: {', '.join(FAILED)}")
 
 
 if __name__ == "__main__":

@@ -340,13 +340,13 @@ def peak_memory(traced, arg_attrs=None) -> int:
 
 def variant_cost(v, mesh=None) -> dict:
     """All layer-3 metrics for one `StepVariant` (trace + lower, never
-    compile)."""
+    compile), traced under `mesh` (default: the smoke mesh)."""
+    import jax
     from repro.analysis.jaxpr_check import main_arg_attrs, trace
-    from repro.compat import set_mesh
     if mesh is None:
         from repro.analysis.invariants import _smoke_parts
         _, _, mesh = _smoke_parts()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         traced = trace(v.fn, *v.args)
         lowered_text = v.fn.lower(*v.args).as_text()
     attrs = main_arg_attrs(lowered_text)
@@ -363,12 +363,13 @@ def variant_cost(v, mesh=None) -> dict:
 
 def measure_variants(variants=None) -> dict:
     """{variant name: metrics} for the whole matrix (or a prebuilt
-    subset)."""
+    subset), each traced under its own mesh (the smoke mesh unless the
+    variant names one)."""
     from repro.analysis.invariants import _smoke_parts, build_variants
     if variants is None:
         variants = build_variants()
-    _, _, mesh = _smoke_parts()
-    return {v.name: variant_cost(v, mesh) for v in variants}
+    _, _, smoke_mesh = _smoke_parts()
+    return {v.name: variant_cost(v, v.mesh or smoke_mesh) for v in variants}
 
 
 # ----------------------------------------------------------------- budget ----
@@ -383,12 +384,15 @@ def load_budget(path) -> dict | None:
 def write_budget(path, measured: dict) -> dict:
     """Freeze `measured` as the committed baseline (atomic replace).  The
     topology is recorded because collective structure is mesh-dependent:
-    a budget measured at a different device count is stale, not wrong."""
+    a budget measured at a different device count is stale, not wrong.
+    The JAX version is recorded too: its lowering moves FLOPs and peak
+    bytes slightly between releases."""
     import jax
     budget = {
         "schema": BUDGET_SCHEMA,
         "topology": {"device_count": jax.device_count(),
-                     "backend": jax.default_backend()},
+                     "backend": jax.default_backend(),
+                     "jax": jax.__version__},
         "tolerances": dict(DEFAULT_TOLERANCES),
         "variants": {k: measured[k] for k in sorted(measured)},
     }

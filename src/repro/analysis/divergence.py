@@ -82,7 +82,7 @@ def check_fn_divergence(fn, args, location: str, mesh=None) -> list[Finding]:
     (later jit calls in this process simply retrace/recompile; this
     checker runs in the one-shot analysis CLI where that costs nothing)."""
     from repro.analysis.jaxpr_check import trace
-    from repro.compat import set_mesh
+    from jax import set_mesh
     import contextlib
     import jax
     ctx = set_mesh(mesh) if mesh is not None else contextlib.nullcontext()

@@ -112,6 +112,9 @@ class StepVariant:
     # the builder's FlatLayout (None on tree paths) — layer 3 attributes
     # collectives to bucket groups by matching operand sizes against it
     layout: object = None
+    # the mesh the step traces under (None: the smoke mesh); a step whose
+    # shard_map names its own mesh must trace under that one
+    mesh: object = None
 
 
 # ------------------------------------------------------- variant builders ----
@@ -147,7 +150,7 @@ def build_variants(combos=None) -> list[StepVariant]:
     `combos` optionally restricts to a subset of
     `EXPECTED_LAYOUT_COUNTS` keys (tests use this to keep one check
     fast)."""
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.core.schedule import BatchPlan, accum_free_plan
     from repro.data.pipeline import MarkovTokens, make_batch
     from repro.distributed.local_step import make_local_sgd_step
@@ -254,7 +257,7 @@ def build_variants(combos=None) -> list[StepVariant]:
 
 def check_variant(v: StepVariant) -> list[Finding]:
     """All invariant findings for one traced step variant (trace-only)."""
-    from repro.compat import set_mesh
+    from jax import set_mesh
     _, _, mesh = _smoke_parts()
     findings = []
 

@@ -84,7 +84,7 @@ def top_pjit_params(jaxpr) -> dict | None:
     if hasattr(jaxpr, "jaxpr"):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             return eqn.params
     return None
 

@@ -44,11 +44,10 @@ Implementations:
                            each host's failed-rung tags), agreement is
                            `broadcast_one_to_all`.
 
-The **persistent compile cache** half (`enable_persistent_cache`) wires
-`jax.config`'s compilation-cache directory for the job, keyed by JAX
-version + backend so restarted or late-joining workers reuse the fleet's
-executables while incompatible toolchains never collide on an entry.  A
-process-wide monitoring listener counts disk-cache hits
+The **persistent compile cache** half (`enable_persistent_cache`) points
+JAX's compilation cache at one fixed directory (`compile_cache_dir`), so
+restarted or late-joining workers reuse the fleet's executables; XLA's
+cache key already carries the JAX version and backend.  A process-wide monitoring listener counts disk-cache hits
 (`/jax/compilation_cache/cache_hits`) so `EngineStats` can distinguish a
 compile served from disk from a fresh XLA build.
 """
@@ -371,9 +370,9 @@ class DistributedCoordinator(Coordinator):
     backend ignores."""
 
     def __init__(self, timeout: float = 120.0):
-        from repro.compat import process_count, process_index
-        self.rank = process_index()
-        self.world = process_count()
+        import jax
+        self.rank = jax.process_index()
+        self.world = jax.process_count()
         del timeout   # accepted for factory symmetry; see class docstring
         self._local: set[str] = set()
         self._known: set[str] = set()
@@ -474,22 +473,41 @@ def disk_cache_hits() -> int:
         return _disk_hits
 
 
-def enable_persistent_cache(cache_dir: str) -> str:
-    """Point JAX's persistent compilation cache at `cache_dir` for this job.
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache: fixed, so every run of this checkout finds the
+# entries of the last one (the path is part of nothing but the lookup)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
-    The actual directory is keyed by JAX version and backend platform —
-    restarted or late-joining workers of the same job resolve to the SAME
-    key and deserialize the fleet's executables instead of recompiling,
-    while a toolchain bump or a CPU-smoke run never poisons the TPU fleet's
-    entries (XLA additionally content-hashes every executable, so entries
-    are safe against stale HLO).  Thresholds are zeroed so even smoke-scale
-    steps persist — the multi-host tests restart an engine and assert a
-    disk hit.  Returns the resolved directory."""
+
+def compile_cache_dir(flag: str = "") -> str:
+    """The persistent compile cache's directory: `$JAX_COMPILATION_CACHE_DIR`
+    verbatim when set (JAX reads it itself), else `flag` (`--compile-cache`)
+    when given, else `<checkout>/.jax_cache`."""
+    return os.environ.get(CACHE_ENV) or flag or DEFAULT_CACHE_DIR
+
+
+def enable_persistent_cache(flag: str = "") -> str:
+    """Turn on JAX's persistent compilation cache at `compile_cache_dir(flag)`.
+
+    Restarted or late-joining workers of the same job resolve to the same
+    directory and deserialize the fleet's executables instead of
+    recompiling; XLA's cache key carries the JAX version, backend and HLO,
+    so one directory serves every toolchain and platform safely.  With
+    `$JAX_COMPILATION_CACHE_DIR` set, no directory is set in code.
+    Thresholds are zeroed so even smoke-scale steps persist — the
+    multi-host tests restart an engine and assert a disk hit.  Returns the
+    directory."""
     import jax
-    path = os.path.join(cache_dir,
-                        f"jax{jax.__version__}-{jax.default_backend()}")
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = compile_cache_dir(flag)
+    if not os.environ.get(CACHE_ENV) and \
+            jax.config.jax_compilation_cache_dir != path:
+        from jax.experimental.compilation_cache import compilation_cache
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+        # the cache binds its directory once per process: rebind it
+        compilation_cache.reset_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _install_hit_listener()
@@ -499,5 +517,5 @@ def enable_persistent_cache(cache_dir: str) -> str:
 __all__ = [
     "CoordinationError", "Coordinator", "NoOpCoordinator", "FileCoordinator",
     "DistributedCoordinator", "make_coordinator",
-    "enable_persistent_cache", "disk_cache_hits",
+    "compile_cache_dir", "enable_persistent_cache", "disk_cache_hits",
 ]

@@ -44,7 +44,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.core.schedule import BatchPlan, LadderShapeError, quantize_to_ladder
 from repro.distributed.coordination import disk_cache_hits, enable_persistent_cache
 from repro.testing.faults import fault_point
@@ -364,7 +364,7 @@ class BucketedEngine(RungCache):
                   uncoordinated, bit-identical to the single-host engine):
                   rung-entry barriers, warmup agreement, failure broadcast.
     persistent_cache_dir : when set, wires JAX's persistent compilation
-                  cache (keyed per job/toolchain) so restarted or
+                  cache (`coordination.compile_cache_dir`) so restarted or
                   late-joining workers deserialize executables from disk;
                   `stats.disk_cache_hits` counts the reuses.
     """

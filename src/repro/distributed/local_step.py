@@ -37,7 +37,6 @@ from repro.optim.adamw import (
 from repro.distributed.params import param_pspecs
 from repro.distributed.sharding import (
     flat_buffer_specs, manual_data_rules, use_sharding_rules)
-from repro.compat import shard_map
 from repro.distributed.train_step import (
     _rules_for, _batch_pspec, _manual_axes, _check_params_impl)
 from repro.launch.mesh import data_axes
@@ -73,7 +72,7 @@ def make_local_sgd_step(model, opt_cfg: AdamWConfig, mesh, *,
                          "params; use stats_impl='flat' with "
                          "params_impl='flat'")
     daxes = data_axes(mesh)
-    manual = _manual_axes(mesh, daxes)
+    manual = _manual_axes(mesh)
     rules = manual_data_rules(_rules_for(mesh), manual)
 
     if params_like is None:
@@ -177,7 +176,7 @@ def make_local_sgd_step(model, opt_cfg: AdamWConfig, mesh, *,
                   else jax.tree.map(lambda _: P(), params_like))
 
     def wrap(batch_like):
-        sm = shard_map(
+        sm = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(p_sm_specs,
                       jax.tree.map(lambda _: P(), opt_like),

@@ -56,14 +56,17 @@ from repro.distributed.sharding import (
     DEFAULT_RULES, MULTIPOD_RULES, manual_data_rules, use_sharding_rules,
     with_sequence_parallel, flat_buffer_specs, gather_flat_buffers,
     shard_flat_buffers)
-from repro.compat import PARTIAL_AUTO_OK, shard_map
 from repro.launch.mesh import data_axes, num_workers
 
 
-def _manual_axes(mesh, daxes):
-    """Manual axes for the hybrid steps: just the data axes when partial-auto
-    shard_map works, the whole mesh on old JAX (see compat.PARTIAL_AUTO_OK)."""
-    return tuple(daxes) if PARTIAL_AUTO_OK else tuple(mesh.axis_names)
+def _manual_axes(mesh) -> tuple[str, ...]:
+    """Manual axes of the hybrid steps' shard_map: the data axes, plus every
+    axis of size 1.  A size-1 axis partitions nothing, so making it manual
+    changes no layout — and a Mosaic (Pallas TPU) call lowers only where
+    every mesh axis is manual, which a nested shard_map cannot provide."""
+    daxes = data_axes(mesh)
+    return tuple(a for a in mesh.axis_names
+                 if a in daxes or mesh.shape[a] == 1)
 
 
 def _tree_zeros_f32(tree):
@@ -275,10 +278,10 @@ def make_fsdp_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
     _check_params_impl(params_impl, variance_impl)
     daxes = data_axes(mesh)
     J = num_workers(mesh)
-    manual = _manual_axes(mesh, daxes)
     base = _rules_for(mesh)
     if sequence_parallel:
         base = with_sequence_parallel(base)
+    manual = _manual_axes(mesh)
     rules = manual_data_rules(base, manual)
 
     if params_like is None:
@@ -389,7 +392,7 @@ def make_fsdp_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
                   else jax.tree.map(lambda _: P(), params_like))
 
     def wrap(batch_like):
-        sm = shard_map(
+        sm = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(p_sm_specs,
                       o_sm_specs,
@@ -467,8 +470,7 @@ def make_accum_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
             if params_impl == "flat":
                 # no sharding constraint on the param buffers: they arrive
                 # as committed jit inputs already carrying the P(daxes)
-                # in_shardings (a redundant constraint costs a copy on
-                # XLA-CPU 0.4.x)
+                # in_shardings
                 pb = tuple(params)
                 g, loss, aux, sq_sum, m_eff, _ = _accumulate_buffers(
                     model.loss, layout, pb, batch, True)

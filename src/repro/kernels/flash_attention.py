@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -2.0e38
 
 
@@ -87,7 +89,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, block_q: int = 256,
-                    block_kv: int = 256, interpret: bool = True):
+                    block_kv: int = 256, interpret: bool | None = None):
     """q: (b, t, h, d); k/v: (b, s, kv_heads, d) with h % kv_heads == 0.
     Returns (b, t, h, d).  Softmax scale is 1/sqrt(d)."""
     b, t, h, d = q.shape
@@ -135,6 +137,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)

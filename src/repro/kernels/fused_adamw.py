@@ -12,9 +12,12 @@ Two entry points:
 * `fused_adamw`       — the original per-tensor update (p', m', v').
 * `fused_adamw_stats` — the flat-buffer path (DESIGN §9): same update over
   one dtype-homogeneous buffer, consuming a traced `clip_scale` and emitting
-  **Σg² of the raw gradient as a kernel byproduct** (one f32 partial per
-  block), so the ACCUM-NORM statistic and the `grad_norm` metric cost zero
-  extra passes over gradient-sized data.
+  **Σg² of the raw gradient as a kernel byproduct** (one lane-aligned
+  (8, 128) f32 partial tile per block), so the ACCUM-NORM statistic and
+  the `grad_norm` metric cost zero extra passes over gradient-sized data.
+
+Both stream 1-D blocks of the flattened operands: no padded or reshaped
+copy of a model-sized buffer is made around the call.
 """
 
 from __future__ import annotations
@@ -25,9 +28,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import LANE, pad_to_blocks, resolve_interpret
+from repro.kernels import (flat_grid, flat_spec, mask_tail, partial_shape,
+                           partial_spec, resolve_interpret, tile_partial,
+                           tiles)
 
-DEFAULT_BLOCK_ROWS = 256
+DEFAULT_BLOCK_ROWS = 256     # 256×128-element blocks of the flat operands
 
 
 def _update(g, p_ref, m_ref, v_ref, scalars_ref, *, beta1, beta2, eps,
@@ -56,9 +61,10 @@ def _kernel(scalars_ref, p_ref, g_ref, m_ref, v_ref,
 
 def _stats_kernel(scalars_ref, p_ref, g_ref, m_ref, v_ref,
                   p_out, m_out, v_out, gsq_out, *, beta1, beta2, eps,
-                  weight_decay):
+                  weight_decay, n, block):
     g_raw = g_ref[...].astype(jnp.float32)
-    gsq_out[0, 0] = jnp.sum(g_raw * g_raw)        # byproduct: pre-clip Σg²
+    # byproduct: pre-clip Σg² (the ragged end of the last block masked out)
+    gsq_out[...] = tile_partial(mask_tail(tiles(g_raw * g_raw), n, block))
     g = g_raw * scalars_ref[0, 3]                  # global-norm clip scale
     p, m, v = _update(g, p_ref, m_ref, v_ref, scalars_ref, beta1=beta1,
                       beta2=beta2, eps=eps, weight_decay=weight_decay)
@@ -74,33 +80,44 @@ def _scalars(lr, c1, c2, clip_scale=1.0):
                       jnp.asarray(clip_scale, jnp.float32)]).reshape(1, 4)
 
 
+def _flat_operands(p, g, m, v, block_rows):
+    """(p, g, m, v) as 1-D operands of one grid, plus (block, blocks)."""
+    pf, block, blocks = flat_grid(p.reshape(-1), block_rows)
+    gf, _, _ = flat_grid(g.reshape(-1), block_rows)
+    mf, _, _ = flat_grid(m.reshape(-1).astype(jnp.float32), block_rows)
+    vf, _, _ = flat_grid(v.reshape(-1).astype(jnp.float32), block_rows)
+    return (pf, gf, mf, vf), block, blocks
+
+
+def _call(kernel, scalars, operands, block, blocks, p_dtype, extra_out, ip):
+    pf = operands[0]
+    spec = flat_spec(block)
+    return pl.pallas_call(
+        kernel,
+        grid=(blocks,),
+        in_specs=[pl.BlockSpec((1, 4), lambda i: (0, 0))] + [spec] * 4,
+        out_specs=[spec, spec, spec] + [partial_spec()] * extra_out,
+        out_shape=[
+            jax.ShapeDtypeStruct(pf.shape, p_dtype),
+            jax.ShapeDtypeStruct(pf.shape, jnp.float32),
+            jax.ShapeDtypeStruct(pf.shape, jnp.float32),
+        ] + [partial_shape(blocks)] * extra_out,
+        interpret=ip,
+    )(scalars, *operands)
+
+
 def fused_adamw(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay, c1, c2,
                 block_rows: int = DEFAULT_BLOCK_ROWS,
                 interpret: bool | None = None):
     """AdamW update on one tensor; returns (p', m', v') with p's shape/dtype."""
     ip = resolve_interpret(interpret)
     shape, n = p.shape, p.size
-    pf, blocks = pad_to_blocks(p.reshape(-1), block_rows)
-    gf, _ = pad_to_blocks(g.reshape(-1), block_rows)
-    mf, _ = pad_to_blocks(m.reshape(-1).astype(jnp.float32), block_rows)
-    vf, _ = pad_to_blocks(v.reshape(-1).astype(jnp.float32), block_rows)
-
+    operands, block, blocks = _flat_operands(p, g, m, v, block_rows)
     kernel = functools.partial(_kernel, beta1=beta1, beta2=beta2, eps=eps,
                                weight_decay=weight_decay)
-    spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    p2, m2, v2 = pl.pallas_call(
-        kernel,
-        grid=(blocks,),
-        in_specs=[pl.BlockSpec((1, 4), lambda i: (0, 0)), spec, spec, spec, spec],
-        out_specs=[spec, spec, spec],
-        out_shape=[
-            jax.ShapeDtypeStruct(pf.shape, p.dtype),
-            jax.ShapeDtypeStruct(mf.shape, jnp.float32),
-            jax.ShapeDtypeStruct(vf.shape, jnp.float32),
-        ],
-        interpret=ip,
-    )(_scalars(lr, c1, c2), pf, gf, mf, vf)
-    unpad = lambda a: a.reshape(-1)[:n].reshape(shape)
+    p2, m2, v2 = _call(kernel, _scalars(lr, c1, c2), operands, block,
+                       blocks, p.dtype, 0, ip)
+    unpad = lambda a: a[:n].reshape(shape)
     return unpad(p2), unpad(m2), unpad(v2)
 
 
@@ -111,31 +128,14 @@ def fused_adamw_stats(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay,
     """Flat-buffer AdamW: one launch over one dtype-homogeneous buffer.
 
     `clip_scale` (traced f32) is folded into the gradient inside the kernel;
-    returns (p', m', v', Σg²) where Σg² is of the RAW (pre-clip) gradient —
-    zero padding contributes nothing to it."""
+    returns (p', m', v', Σg²) where Σg² is of the RAW (pre-clip) gradient."""
     ip = resolve_interpret(interpret)
     shape, n = p.shape, p.size
-    pf, blocks = pad_to_blocks(p.reshape(-1), block_rows)
-    gf, _ = pad_to_blocks(g.reshape(-1), block_rows)
-    mf, _ = pad_to_blocks(m.reshape(-1).astype(jnp.float32), block_rows)
-    vf, _ = pad_to_blocks(v.reshape(-1).astype(jnp.float32), block_rows)
-
+    operands, block, blocks = _flat_operands(p, g, m, v, block_rows)
     kernel = functools.partial(_stats_kernel, beta1=beta1, beta2=beta2,
-                               eps=eps, weight_decay=weight_decay)
-    spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    part = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    p2, m2, v2, gsq = pl.pallas_call(
-        kernel,
-        grid=(blocks,),
-        in_specs=[pl.BlockSpec((1, 4), lambda i: (0, 0)), spec, spec, spec, spec],
-        out_specs=[spec, spec, spec, part],
-        out_shape=[
-            jax.ShapeDtypeStruct(pf.shape, p.dtype),
-            jax.ShapeDtypeStruct(mf.shape, jnp.float32),
-            jax.ShapeDtypeStruct(vf.shape, jnp.float32),
-            jax.ShapeDtypeStruct((blocks, 1), jnp.float32),
-        ],
-        interpret=ip,
-    )(_scalars(lr, c1, c2, clip_scale), pf, gf, mf, vf)
-    unpad = lambda a: a.reshape(-1)[:n].reshape(shape)
+                               eps=eps, weight_decay=weight_decay,
+                               n=operands[0].shape[0], block=block)
+    p2, m2, v2, gsq = _call(kernel, _scalars(lr, c1, c2, clip_scale),
+                            operands, block, blocks, p.dtype, 1, ip)
+    unpad = lambda a: a[:n].reshape(shape)
     return unpad(p2), unpad(m2), unpad(v2), jnp.sum(gsq)

@@ -16,6 +16,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import _backend_is_tpu, resolve_interpret as _default_resolve
 from repro.kernels import ref
@@ -25,6 +26,7 @@ from repro.kernels.fused_adamw import (
 from repro.kernels.fused_stats import fused_stats as _fused_stats
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm
 from repro.kernels.flash_attention import flash_attention as _flash_attention
+from repro.launch.mesh import data_axes
 
 
 def _default_interpret() -> bool:
@@ -95,6 +97,38 @@ def fused_adamw_stats(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay,
 # REPRO_PALLAS_INTERPRET: interpret-mode Pallas is for validating kernels,
 # not for running the per-step tail.
 
+def on_local_shards(kernel, bufs, scalars=(), *, n_bufs_out: int,
+                    n_sums: int):
+    """Run `kernel(*bufs, *scalars) -> (out_bufs, partial_sums)` on every
+    device's local shard of 1-D flat buffers.
+
+    GSPMD cannot partition a Mosaic call, so where the context mesh still
+    has Auto axes the call is wrapped in a `shard_map` manual over them.
+    The buffers carry the DESIGN §9 data-axis sharding (`P(data axes)` of
+    the axes still auto; replicated when the data axes are already manual,
+    as inside the FSDP-Norm step), so each device updates its own bucket
+    shard and the per-shard partial sums are `psum`'d over those axes.
+    With no context mesh the kernel runs as is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = () if mesh.empty else tuple(mesh.auto_axes)
+    if not auto:
+        return kernel(*bufs, *scalars)
+    daxes = tuple(a for a in data_axes(mesh) if a in auto)
+    spec = P(daxes) if daxes else P()
+
+    def body(*args):
+        out_bufs, sums = kernel(*args)
+        if daxes:
+            sums = tuple(jax.lax.psum(s, daxes) for s in sums)
+        return tuple(out_bufs), tuple(sums)
+
+    scalars = tuple(jnp.asarray(s, jnp.float32) for s in scalars)
+    return jax.shard_map(
+        body, in_specs=(spec,) * len(bufs) + (P(),) * len(scalars),
+        out_specs=((spec,) * n_bufs_out, (P(),) * n_sums),
+        axis_names=set(auto), check_vma=False)(*bufs, *scalars)
+
+
 def stats_flat(x, y):
     """Backend-dispatched single-pass (Σ(x−y)², Σy²) over flat buffers.
 
@@ -103,7 +137,10 @@ def stats_flat(x, y):
     J-way-sharded bucket costs 1/J of the launch grid per worker (zero
     shard-padding contributes nothing to either sum)."""
     if _backend_is_tpu():
-        return _fused_stats(x, y, interpret=False)
+        _, sums = on_local_shards(
+            lambda a, b: ((), _fused_stats(a, b, interpret=False)), (x, y),
+            n_bufs_out=0, n_sums=2)
+        return sums
     return ref.fused_stats_ref(x, y)
 
 
@@ -115,10 +152,16 @@ def adamw_flat(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay, c1, c2,
     sharded-bucket FSDP-Norm step each worker updates only its 1/J bucket
     shard, so per-worker update flops and moment traffic drop by J."""
     if _backend_is_tpu():
-        return _fused_adamw_stats(p, g, m, v, lr=lr, beta1=beta1, beta2=beta2,
-                                  eps=eps, weight_decay=weight_decay, c1=c1,
-                                  c2=c2, clip_scale=clip_scale,
-                                  interpret=False)
+        def kernel(p, g, m, v, lr, c1, c2, clip_scale):
+            *bufs, gsq = _fused_adamw_stats(
+                p, g, m, v, lr=lr, beta1=beta1, beta2=beta2, eps=eps,
+                weight_decay=weight_decay, c1=c1, c2=c2,
+                clip_scale=clip_scale, interpret=False)
+            return bufs, (gsq,)
+        (p2, m2, v2), (gsq,) = on_local_shards(
+            kernel, (p, g, m, v), (lr, c1, c2, clip_scale),
+            n_bufs_out=3, n_sums=1)
+        return p2, m2, v2, gsq
     return ref.adamw_stats_ref(p, g, m, v, lr=lr, beta1=beta1, beta2=beta2,
                                eps=eps, weight_decay=weight_decay, c1=c1,
                                c2=c2, clip_scale=clip_scale)

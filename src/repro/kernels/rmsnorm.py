@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_BLOCK_ROWS = 128
 
 
@@ -25,7 +27,8 @@ def _kernel(x_ref, s_ref, o_ref, *, eps):
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-6,
-            block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True):
+            block_rows: int = DEFAULT_BLOCK_ROWS,
+            interpret: bool | None = None):
     """x: (..., d); scale: (d,). Returns same shape/dtype as x."""
     shape = x.shape
     d = shape[-1]
@@ -44,6 +47,6 @@ def rmsnorm(x, scale, *, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, d), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xf, scale.reshape(1, d))
     return out[:rows].reshape(shape)

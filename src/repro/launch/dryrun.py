@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs import ALL_ARCHS, ASSIGNED_ARCHS, get_config
 from repro.configs.shapes import INPUT_SHAPES, input_specs
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.launch.mesh import make_production_mesh, num_workers
 from repro.launch.roofline import (
     roofline_terms, parse_collectives, model_flops_per_step)

@@ -7,7 +7,16 @@ XLA_FLAGS before any jax initialization.
 
 from __future__ import annotations
 
-from repro.compat import make_mesh, set_mesh  # noqa: F401  (set_mesh re-export)
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(axis_shapes, axis_names, **kwargs):
+    """`jax.make_mesh` with all-Auto axis types: the steps place shardings
+    through GSPMD propagation and `shard_map`, not explicit-sharding types
+    (`jax.make_mesh` defaults to Explicit)."""
+    kwargs.setdefault("axis_types", (AxisType.Auto,) * len(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

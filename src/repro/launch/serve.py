@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.launch.mesh import make_host_mesh
 from repro.distributed.serve_step import make_decode_step, make_prefill
 from repro.models import build_model

@@ -4,8 +4,11 @@ Usable as a library (`run_training(TrainJob(...))` — benchmarks and examples
 call this) and as a CLI:
 
     PYTHONPATH=src python -m repro.launch.train \
-        --arch microllama-300m --smoke --schedule adaptive --eta 0.2 \
+        --arch microllama-300m --schedule adaptive --eta 0.2 \
         --steps 200 --seq-len 128 --max-global-batch 256
+
+runs the 2-layer smoke preset; `--no-smoke --remat full` runs the published
+widths with activation recomputation (how a full-width model fits one chip).
 
 The loop is Algorithm 1: for each step the controller's BatchPlan determines
 the (M, J*micro, seq) stacked batch; the fused distributed step accumulates
@@ -28,6 +31,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.controller import (
@@ -41,8 +45,9 @@ from repro.data.pipeline import (
 from repro.distributed.coordination import (
     CoordinationError, enable_persistent_cache, make_coordinator)
 from repro.distributed.engine import BucketedEngine
+from repro.distributed.params import param_pspecs
 from repro.distributed.train_step import make_fsdp_norm_step, make_accum_norm_step
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.launch.mesh import make_host_mesh, num_workers
 from repro.models import build_model
 from repro.optim.adamw import (
@@ -56,7 +61,8 @@ from repro.testing.faults import fault_point
 @dataclass
 class TrainJob:
     arch: str = "microllama-300m"
-    smoke: bool = True
+    smoke: bool = True                    # --no-smoke: published widths
+    remat: str = "none"                   # none | full (ModelConfig.remat)
     schedule: str = "adaptive"            # adaptive | constant | stagewise
     step_impl: str = "fsdp_norm"          # fsdp_norm | accum_norm
     variance_impl: str = "scalar"         # scalar | paper
@@ -117,7 +123,8 @@ class TrainJob:
                                           # (file coord; 'distributed' uses
                                           # the jax.distributed runtime's own
                                           # collective timeouts)
-    # persistent XLA compile cache dir (keyed per jax version + backend):
+    # persistent XLA compile cache dir; $JAX_COMPILATION_CACHE_DIR wins, and
+    # empty means <checkout>/.jax_cache (coordination.compile_cache_dir):
     # restarted / late-joining workers deserialize executables from disk
     compile_cache: str = ""
     eval_every: int = 25
@@ -144,11 +151,49 @@ def _sds(batch):
     return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
 
 
+def _shardings(mesh, specs):
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda s: isinstance(s, P))
+
+
+def init_train_state(model, key, mesh, wrap, p_specs, o_specs, *,
+                     stats_impl: str, params_impl: str):
+    """(params, opt_state) born in the step's own layout (`wrap`, `p_specs`,
+    `o_specs` from a step builder): each device materializes only its shard,
+    so no full copy of the state ever lands on one device.  The weight tree
+    is drawn first and flattened after, so every residency starts from
+    bit-identical weights — and the same weights as an eager `model.init`."""
+    layout = wrap.flat_layout
+    params_like = jax.eval_shape(model.init, key)
+    draw_specs = (p_specs if params_impl == "tree" else
+                  param_pspecs(params_like, mesh, fsdp=True))
+    params = jax.jit(model.init,
+                     out_shardings=_shardings(mesh, draw_specs))(key)
+    # flat moment buckets are padded to J-divisible sizes and SHARDED over
+    # the data axes (DESIGN §9) — the state layout must match the step's
+    opt_state = jax.jit(
+        lambda: (init_adamw_flat(params_like, layout=layout)
+                 if stats_impl == "flat" else init_adamw(params_like)),
+        out_shardings=_shardings(mesh, o_specs))()
+    if params_impl == "flat":
+        # flat residency (DESIGN §10): the ONLY pack of the whole run —
+        # from here on gradients are born flat and params stay buffers
+        params = jax.jit(lambda p: tuple(layout.flatten(p)),
+                         out_shardings=_shardings(mesh, tuple(p_specs)))(params)
+    return params, opt_state
+
+
+def model_config(job: TrainJob):
+    """The job's ModelConfig: smoke preset or published widths, with the
+    job's activation-recomputation policy."""
+    cfg = get_smoke_config(job.arch) if job.smoke else get_config(job.arch)
+    return cfg.replace(remat=job.remat)
+
+
 def run_training(job: TrainJob) -> dict:
-    if job.compile_cache:
-        # before any compile: every executable this job builds lands in (or
-        # comes from) the per-job persistent cache
-        enable_persistent_cache(job.compile_cache)
+    # before any compile: every executable this job builds lands in (or
+    # comes from) the persistent cache
+    enable_persistent_cache(job.compile_cache)
     # run identity for the file coordinator: a digest of the job config
     # minus per-host fields, so every rank of THIS job (including restarts)
     # shares one coordination namespace while a different job pointed at a
@@ -164,10 +209,10 @@ def run_training(job: TrainJob) -> dict:
     coordinator = make_coordinator(job.coord, root=job.coord_dir,
                                    rank=job.coord_rank, world=job.coord_world,
                                    timeout=job.coord_timeout, run_id=run_id)
-    cfg = get_smoke_config(job.arch) if job.smoke else get_config(job.arch)
+    cfg = model_config(job)
     model = build_model(cfg)
     key = jax.random.PRNGKey(job.seed)
-    params = model.init(key)
+    params_like = jax.eval_shape(model.init, key)
 
     n_dev = len(jax.devices())
     d = job.mesh_data or max(1, n_dev // job.mesh_model)
@@ -177,28 +222,21 @@ def run_training(job: TrainJob) -> dict:
     opt_cfg = AdamWConfig(lr=job.peak_lr, weight_decay=job.weight_decay,
                           grad_clip=job.grad_clip)
     if job.step_impl == "fsdp_norm":
-        wrap, _, _ = make_fsdp_norm_step(model, opt_cfg, mesh,
-                                         variance_impl=job.variance_impl,
-                                         stats_impl=job.stats_impl,
-                                         params_impl=job.params_impl,
-                                         params_like=params)
+        wrap, p_specs, o_specs = make_fsdp_norm_step(
+            model, opt_cfg, mesh, variance_impl=job.variance_impl,
+            stats_impl=job.stats_impl, params_impl=job.params_impl,
+            params_like=params_like)
     else:
-        wrap, _, _ = make_accum_norm_step(model, opt_cfg, mesh,
-                                          stats_impl=job.stats_impl,
-                                          params_impl=job.params_impl,
-                                          params_like=params)
+        wrap, p_specs, o_specs = make_accum_norm_step(
+            model, opt_cfg, mesh, stats_impl=job.stats_impl,
+            params_impl=job.params_impl, params_like=params_like)
     # the ONE per-step-signature layout the builder compiled against —
     # shared with the optimizer state, the residency conversion, and the
     # checkpoint metadata (None on the pure tree path)
     layout = wrap.flat_layout
-    # flat moment buckets are padded to J-divisible sizes and SHARDED over
-    # the data axes (DESIGN §9) — the state layout must match the step's
-    opt_state = (init_adamw_flat(params, shard_divisor=workers, layout=layout)
-                 if job.stats_impl == "flat" else init_adamw(params))
-    if job.params_impl == "flat":
-        # flat residency (DESIGN §10): the ONLY pack of the whole run —
-        # from here on gradients are born flat and params stay buffers
-        params = tuple(layout.flatten(params))
+    params, opt_state = init_train_state(
+        model, key, mesh, wrap, p_specs, o_specs,
+        stats_impl=job.stats_impl, params_impl=job.params_impl)
 
     if job.bucket_ladder == "off":
         ladder = None
@@ -312,7 +350,7 @@ def run_training(job: TrainJob) -> dict:
 
     history = {"step": [], "loss": [], "val_loss": [], "global_batch": [],
                "T": [], "var_l1": [], "grad_sqnorm": [], "samples": [],
-               "time": [], "accum_steps": [], "opt_steps": [],
+               "time": [], "step_s": [], "accum_steps": [], "opt_steps": [],
                "pred_rung": [], "pred_eta": []}
     history["workers"] = workers
     samples = 0
@@ -398,6 +436,7 @@ def run_training(job: TrainJob) -> dict:
                 # injection site: the Nth call is the Nth step of the RUN,
                 # not of this process — chaos tests key kill rules on it
                 fault_point("train.step", step=step + 1)
+                t_step = time.time()
                 if schedule is not None:
                     plan = schedule.plan_for(samples, total_samples)
                 else:
@@ -481,6 +520,10 @@ def run_training(job: TrainJob) -> dict:
                     samples += plan.global_batch
                     exec_plan, opt_steps = plan, 1
                 step += 1
+                # this step's wall time, fenced on the updated state (input
+                # assembly and dispatch included; controller and eval not)
+                jax.block_until_ready((params, opt_state))
+                history["step_s"].append(time.time() - t_step)
                 if job.schedule == "adaptive":
                     ctrl = controller_update(ctrl_cfg, ctrl, var_l1, gsq)
                 if engine is not None:
@@ -583,12 +626,15 @@ def summarize(history: dict) -> dict:
     return out
 
 
-def main(argv=None):
+def parse_job(argv=None) -> TrainJob:
+    """The CLI's TrainJob: one flag per field (`--no-x` for booleans)."""
     p = argparse.ArgumentParser()
     for f in dataclasses.fields(TrainJob):
         name = "--" + f.name.replace("_", "-")
         if f.type == "bool" or isinstance(f.default, bool):
-            p.add_argument(name, action="store_true", default=f.default)
+            # --x / --no-x: a default-True switch can be turned off
+            p.add_argument(name, action=argparse.BooleanOptionalAction,
+                           default=f.default)
         elif f.name == "stages":
             p.add_argument(name, type=str, default=None,
                            help="e.g. '0.025:16,0.025:64,0.95:256'")
@@ -604,9 +650,15 @@ def main(argv=None):
                              (s.split(":") for s in kw["stages"].split(",")))
     elif kw.get("stages") is None:
         kw["stages"] = TrainJob.stages
-    job = TrainJob(**kw)
-    hist = run_training(job)
+    return TrainJob(**kw)
+
+
+def main(argv=None) -> dict:
+    """CLI entry point: prints the JSON summary and returns the full run
+    history (engine stats included) to in-process callers."""
+    hist = run_training(parse_job(argv))
     print(json.dumps(summarize(hist), indent=2))
+    return hist
 
 
 if __name__ == "__main__":

@@ -6,13 +6,20 @@ import jax
 import jax.numpy as jnp
 
 
+def _normal(key, shape):
+    """Standard normal draw, fenced so that a jitted (sharded) model init
+    cannot fold the caller's scale into the draw's own constants: jitted
+    and eager initialisation then give the same bits."""
+    return jax.lax.optimization_barrier(jax.random.normal(key, shape))
+
+
 def normal_init(key, shape, dtype, stddev: float = 0.02):
-    return (stddev * jax.random.normal(key, shape)).astype(dtype)
+    return (stddev * _normal(key, shape)).astype(dtype)
 
 
 def fan_in_init(key, shape, dtype, fan_in: int | None = None):
     fi = fan_in if fan_in is not None else shape[0]
-    return (jax.random.normal(key, shape) / jnp.sqrt(jnp.maximum(fi, 1))).astype(dtype)
+    return (_normal(key, shape) / jnp.sqrt(jnp.maximum(fi, 1))).astype(dtype)
 
 
 def zeros_init(_key, shape, dtype):

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.common import normal_init, zeros_init, split_keys
 from repro.models.config import SSMConfig
@@ -29,7 +30,8 @@ def init_ssd(key, d_model: int, s: SSMConfig, dtype):
         "w_in": normal_init(keys[0], (d_model, 2 * di + 2 * s.state_dim + nh), dtype),
         "conv_w": normal_init(keys[1], (s.conv_width, conv_ch), dtype),
         "conv_b": zeros_init(keys[1], (conv_ch,), dtype),
-        "a_log": jnp.log(jnp.linspace(1.0, 16.0, nh)).astype(jnp.float32),
+        # a host constant: jitted and eager initialisation agree bit for bit
+        "a_log": jnp.asarray(np.log(np.linspace(1.0, 16.0, nh)), jnp.float32),
         "dt_bias": zeros_init(keys[2], (nh,), jnp.float32),
         "d_skip": jnp.ones((nh,), jnp.float32),
         "norm_scale": jnp.ones((di,), dtype),

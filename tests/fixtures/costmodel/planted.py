@@ -29,7 +29,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
+from jax import shard_map
+
+from repro.launch.mesh import make_mesh
 
 
 def fixture_mesh():

@@ -59,7 +59,7 @@ def _x(mesh):
 def test_planted_extra_allgather_flagged(planted, mesh, tmp_path):
     """The planted extra all_gather shows up in the profile (new kind, new
     bytes) and trips the budget diff with an exact op-count finding."""
-    from repro.compat import set_mesh
+    from jax import set_mesh
     x = _x(mesh)
     with set_mesh(mesh):
         clean = trace(planted.clean_step(mesh), x)
@@ -133,7 +133,7 @@ def test_planted_rank_dependent_order_flagged(planted, mesh):
 def test_planted_cond_branch_mismatch_flagged(planted, mesh):
     """A psum under only one cond branch -> divergence-cond, and the raw
     mismatch API names the cond site."""
-    from repro.compat import set_mesh
+    from jax import set_mesh
     x = _x(mesh)
     with set_mesh(mesh):
         traced = trace(planted.cond_collective_step(mesh), x)
@@ -149,7 +149,7 @@ def test_planted_cond_branch_mismatch_flagged(planted, mesh):
 def test_collective_signature_orders_and_scopes(planted, mesh):
     """The signature is ordered and scope-tagged: clean step = one psum,
     extra-gather step = psum then all_gather, in emission order."""
-    from repro.compat import set_mesh
+    from jax import set_mesh
     x = _x(mesh)
     with set_mesh(mesh):
         sig = collective_signature(trace(planted.extra_gather_step(mesh), x))
@@ -166,7 +166,8 @@ def _fake_variant(planted, mesh):
     from repro.analysis.invariants import LayoutCounts, StepVariant
     return StepVariant(name="planted/clean", fn=planted.clean_step(mesh),
                        args=(_x(mesh),), expected=LayoutCounts(0, 0, 0),
-                       spec_prefix=[], flat_groups=[], layout=None)
+                       spec_prefix=[], flat_groups=[], layout=None,
+                       mesh=mesh)
 
 
 def test_budget_roundtrip_update_and_drift(planted, mesh, tmp_path):
@@ -253,7 +254,7 @@ def test_engine_lower_step_exposes_hlo_without_compiling():
     """`BucketedEngine.lower_step` hands layer 3 the lowered module (text
     with donation aliasing visible) while stats prove nothing compiled and
     the cache stayed empty."""
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.configs import get_smoke_config
     from repro.core.schedule import parse_ladder
     from repro.data.pipeline import MarkovTokens, make_batch

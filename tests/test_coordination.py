@@ -266,7 +266,9 @@ print("STATS", json.dumps({"rank": rank, "before": before, "after": after,
 
 
 def _launch_ranks(code, args, n=2, timeout=180):
-    env = dict(os.environ)
+    # the cache directory is the test's own, not one set from outside
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     procs = [subprocess.Popen([sys.executable, "-c", code, str(r), *args],
                               stdout=subprocess.PIPE, stderr=subprocess.PIPE,
@@ -380,6 +382,60 @@ def test_persistent_cache_reused_across_engine_restart(tmp_path):
     assert s_warm["compiles"] == s_cold["compiles"] == 1   # 1 trace each run
 
 
+@pytest.mark.parametrize("env,flag,want", [
+    (None, "", "default"),        # neither: <checkout>/.jax_cache
+    (None, "flag", "flag"),       # --compile-cache
+    ("env", "flag", "env"),       # $JAX_COMPILATION_CACHE_DIR wins, verbatim
+])
+def test_compile_cache_dir_resolution(monkeypatch, tmp_path, env, flag,
+                                      want):
+    from repro.distributed.coordination import (
+        CACHE_ENV, DEFAULT_CACHE_DIR, compile_cache_dir)
+    assert DEFAULT_CACHE_DIR == os.path.join(REPO, ".jax_cache")
+    if env:
+        monkeypatch.setenv(CACHE_ENV, str(tmp_path / env))
+    else:
+        monkeypatch.delenv(CACHE_ENV, raising=False)
+    got = compile_cache_dir(str(tmp_path / flag) if flag else "")
+    assert got == (DEFAULT_CACHE_DIR if want == "default"
+                   else str(tmp_path / want))
+
+
+_CACHE_PLACEMENT = """
+import json, os, sys
+import jax, jax.numpy as jnp
+from repro.distributed.coordination import enable_persistent_cache
+path = enable_persistent_cache(sys.argv[1])
+jax.block_until_ready(jax.jit(lambda x: x * 3 + 1)(jnp.arange(8.0)))
+print("CACHE", json.dumps({"path": path,
+                           "config": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_entries_land_in_resolved_dir(tmp_path, from_env):
+    """Entries land in exactly the resolved directory — no version or
+    backend subdirectory — and with $JAX_COMPILATION_CACHE_DIR set the code
+    sets no directory of its own."""
+    target = tmp_path / "cache"
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(target)
+    flag = str(tmp_path / "unused") if from_env else str(target)
+    res = subprocess.run([sys.executable, "-c", _CACHE_PLACEMENT, flag],
+                         capture_output=True, text=True, env=env,
+                         timeout=180)
+    assert res.returncode == 0, res.stderr
+    got = json.loads(next(l for l in res.stdout.splitlines()
+                          if l.startswith("CACHE")).split(" ", 1)[1])
+    assert got == {"path": str(target), "config": str(target)}
+    entries = os.listdir(target)
+    assert entries and all(not (target / e).is_dir() for e in entries)
+    assert not (tmp_path / "unused").exists()
+
+
 def test_coord_none_bit_identical_to_uncoordinated(tmp_path):
     """--coord=none must be byte-for-byte the PR 4 single-host engine: same
     losses, same engine stats, and a file-coordinated world-of-one run also
@@ -456,17 +512,22 @@ def test_agree_fails_fast_when_leader_is_dead(tmp_path):
 def test_live_rank_never_reads_as_dead(tmp_path):
     """The heartbeat thread keeps a healthy rank fresh well past dead_after;
     only after it stops does the rank turn stale."""
+    # dead_after is 20 heartbeats: a heartbeat thread starved for a few
+    # hundred ms by a loaded machine is still alive
     d = str(tmp_path / "coord")
-    c0 = FileCoordinator(d, 0, 2, heartbeat_s=0.05, dead_after=0.25)
-    c1 = FileCoordinator(d, 1, 2, heartbeat_s=0.05, dead_after=0.25)
-    time.sleep(0.5)                    # several dead_after windows
-    assert c0.dead_ranks() == frozenset()
+    c0 = FileCoordinator(d, 0, 2, heartbeat_s=0.05, dead_after=1.0)
+    c1 = FileCoordinator(d, 1, 2, heartbeat_s=0.05, dead_after=1.0)
+    for _ in range(3):                 # past several dead_after windows
+        time.sleep(0.5)
+        assert c0.dead_ranks() == frozenset()
     c1.close()
-    time.sleep(0.5)
+    deadline = time.time() + 10.0
+    while c0.dead_ranks() != frozenset({1}) and time.time() < deadline:
+        time.sleep(0.1)
     assert c0.dead_ranks() == frozenset({1})
     # a never-seen rank is only MISSING (could still be launching), not dead
     solo = FileCoordinator(str(tmp_path / "c2"), 0, 3, heartbeat_s=0.05,
-                           dead_after=0.25)
-    time.sleep(0.4)
+                           dead_after=1.0)
+    time.sleep(1.5)
     assert solo.dead_ranks() == frozenset()
     solo.close(), c0.close()

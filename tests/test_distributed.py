@@ -27,7 +27,7 @@ def test_param_pspecs_divisibility_fallback():
 def test_fsdp_norm_matches_bruteforce(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_smoke_config
 from repro.models import build_model
 from repro.launch.mesh import make_host_mesh
@@ -70,7 +70,7 @@ def test_paper_vs_scalar_variance_equal(subproc):
     full-vector all-reduce formulation (DESIGN §7.1)."""
     out = subproc("""
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_smoke_config
 from repro.models import build_model
 from repro.launch.mesh import make_host_mesh
@@ -107,7 +107,7 @@ def test_2d_mesh_train_and_serve(subproc):
     and an SSM arch."""
     out = subproc("""
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_smoke_config
 from repro.models import build_model
 from repro.launch.mesh import make_host_mesh
@@ -149,7 +149,7 @@ def test_mini_dryrun_all_shapes(subproc):
     config on an 8-device 4x2 mesh (the structural twin of the 512-chip run)."""
     out = subproc("""
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
+from jax import set_mesh
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.configs import get_smoke_config
 from repro.models import build_model

@@ -283,7 +283,7 @@ def test_flat_opt_state_roundtrip():
 # ------------------------------------------- step-level equivalence ----
 
 def _tiny_step_setup():
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.configs import get_smoke_config
     from repro.models import build_model
     from repro.launch.mesh import make_host_mesh
@@ -464,7 +464,7 @@ def test_flat_moments_sharded_over_data_axes(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_smoke_config
 from repro.models import build_model
 from repro.launch.mesh import make_host_mesh

@@ -32,6 +32,27 @@ def test_sqdiff_norm_property(n):
     assert abs(got - want) <= 1e-4 * max(want, 1.0)
 
 
+@pytest.mark.parametrize("n", [2 * 32768 + 1000, 3 * 32768])
+def test_flat_kernels_span_blocks(n):
+    """Several 32k-element blocks, the last one ragged or whole: the flat
+    kernels mask the ragged end out of their sums and drop its writes."""
+    ks = jax.random.split(KEY, 4)
+    x, y = (jax.random.normal(k, (n,)) for k in ks[:2])
+    np.testing.assert_allclose(float(ops.sqdiff_norm(x, y)),
+                               float(ref.sqdiff_norm_ref(x, y)), rtol=1e-5)
+    for a, b in zip(ops.fused_stats(x, y), ref.fused_stats_ref(x, y)):
+        np.testing.assert_allclose(float(a), float(b), rtol=1e-5)
+    m = jax.random.normal(ks[2], (n,))
+    v = jnp.abs(jax.random.normal(ks[3], (n,)))
+    kw = dict(lr=3e-4, beta1=0.9, beta2=0.95, eps=1e-8, weight_decay=0.1,
+              c1=0.7, c2=0.4, clip_scale=0.37)
+    got = ops.fused_adamw_stats(x, y, m, v, **kw)
+    want = ref.adamw_stats_ref(x, y, m, v, **kw)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+
+
 @pytest.mark.parametrize("shape", [(100,), (1024,), (31, 67)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fused_adamw_sweep(shape, dtype):

@@ -7,7 +7,7 @@ import pytest
 def test_local_sgd_round_and_divergence_signal(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_smoke_config
 from repro.models import build_model
 from repro.launch.mesh import make_host_mesh
@@ -72,7 +72,7 @@ def test_local_sgd_flat_resident_matches_tree():
     ZERO packs in the traced round."""
     import numpy as np
     import jax.numpy as jnp
-    from repro.compat import set_mesh
+    from jax import set_mesh
     from repro.configs import get_smoke_config
     from repro.models import build_model
     from repro.launch.mesh import make_host_mesh

@@ -18,7 +18,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_smoke_config
 from repro.core.schedule import BatchPlan
 from repro.data.pipeline import MarkovTokens, make_batch
@@ -137,7 +137,7 @@ def test_flat_resident_param_specs_two_device(subproc):
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_smoke_config
 from repro.models import build_model
 from repro.launch.mesh import make_host_mesh
