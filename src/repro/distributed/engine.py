@@ -84,6 +84,9 @@ class EngineStats:
     # a jit): sibling jits like train.py's eval fn count too, so read this
     # as "executables this job reused from disk", not an engine-only figure
     disk_cache_hits: int = 0
+    # seconds `RungCache.lookup` blocked for an executable: a pending
+    # warmup's result, a foreground build, or another caller's build
+    lookup_wait_s: float = 0.0
 
     @property
     def hit_rate(self) -> float:
@@ -112,6 +115,7 @@ class EngineStats:
             "desyncs": self.desyncs,
             "coord_downgrades": self.coord_downgrades,
             "disk_cache_hits": self.disk_cache_hits,
+            "lookup_wait_s": self.lookup_wait_s,
         }
 
 
@@ -211,7 +215,8 @@ class RungCache:
             fut = self._pending.pop(key, None)
         if fut is not None:
             try:
-                fn = fut.result()  # warmup finished or finishes now
+                with self._waiting():
+                    fn = fut.result()  # warmup finished or finishes now
             except Exception as e:               # noqa: BLE001 — surfaced in drain()
                 self._record_warmup_failure(e, key)
             else:
@@ -232,7 +237,8 @@ class RungCache:
             if mine:
                 try:
                     fault_point("engine.compile", key=key)
-                    fn = self._build(build_arg)
+                    with self._waiting():
+                        fn = self._build(build_arg)
                 except BaseException as e:
                     with self._lock:
                         self._building.pop(key, None)
@@ -250,9 +256,21 @@ class RungCache:
             # interrupt raised in THIS thread while blocked must escape, or
             # Ctrl-C during a compile wait would silently retry forever.
             try:
-                bfut.result()
+                with self._waiting():
+                    bfut.result()
             except Exception:                  # noqa: BLE001 — builder raised
                 pass
+
+    @contextlib.contextmanager
+    def _waiting(self):
+        """Count the enclosed wait in `stats.lookup_wait_s`."""
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            waited = time.perf_counter() - t0
+            with self._lock:
+                self.stats.lookup_wait_s += waited
 
     def cached(self, key: tuple) -> bool:
         """True when `key`'s executable is already resident (no build or

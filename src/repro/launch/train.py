@@ -55,6 +55,7 @@ from repro.optim.adamw import (
 from repro.checkpoint.store import (
     FLAT_PARAMS_META, flat_params_metadata, latest_step, restore_checkpoint,
     save_checkpoint)
+from repro import spans
 from repro.testing.faults import fault_point
 
 
@@ -351,7 +352,7 @@ def run_training(job: TrainJob) -> dict:
     history = {"step": [], "loss": [], "val_loss": [], "global_batch": [],
                "T": [], "var_l1": [], "grad_sqnorm": [], "samples": [],
                "time": [], "step_s": [], "accum_steps": [], "opt_steps": [],
-               "pred_rung": [], "pred_eta": []}
+               "pred_rung": [], "pred_eta": [], "lookup_wait_s": []}
     history["workers"] = workers
     samples = 0
     step = 0
@@ -436,13 +437,16 @@ def run_training(job: TrainJob) -> dict:
                 # injection site: the Nth call is the Nth step of the RUN,
                 # not of this process — chaos tests key kill rules on it
                 fault_point("train.step", step=step + 1)
+                # each phase below is a profiler span (repro.spans) carrying
+                # the 1-based step; they tile the iteration and open only
+                # after the fault point, where a caller may start a trace
+                n = step + 1
                 t_step = time.time()
                 if schedule is not None:
                     plan = schedule.plan_for(samples, total_samples)
                 else:
                     plan = ctrl.plan
                 seq_len = seq_len_for(samples)
-                batch_np = make_batch(source, step, plan, seq_len, extra_specs)
                 bucket = None
                 if engine is not None:
                     # no max_global clamp here: the ladder top is built to
@@ -450,6 +454,7 @@ def run_training(job: TrainJob) -> dict:
                     # configured above max_global_batch (the controller
                     # clamps its own plans)
                     bucket = engine.bucket_for(plan.global_batch)
+                    wait_before = engine.stats.lookup_wait_s
 
                 # accum-free low rungs (DESIGN §14): re-plan this scheduled
                 # step as M optimizer steps of the same (J·mb) microbatch.
@@ -471,61 +476,87 @@ def run_training(job: TrainJob) -> dict:
                           and (job.schedule != "adaptive" or not tested
                                or signal_alive))
 
+                with spans.span(spans.BATCH, n):
+                    batch_np = make_batch(source, step, plan, seq_len,
+                                          extra_specs)
+                    if engine is not None and not use_af:
+                        batch_np = pad_to_bucket(batch_np, plan, bucket)
+
                 if use_af:
                     sub_plan, repeats = accum_free_plan(plan)
                     sub_losses = []
                     for m in range(repeats):
-                        sub_np = {k: v[m:m + 1] for k, v in batch_np.items()}
+                        with spans.span(spans.BATCH, n):
+                            sub_np = {k: v[m:m + 1]
+                                      for k, v in batch_np.items()}
+                        with spans.span(spans.LOOKUP, n):
+                            if engine is not None:
+                                # (1, J·mb) is on the ladder by construction
+                                # (the accum-free rungs appended above)
+                                step_fn = engine.get_step(sub_np)
+                                engine.observe(sub_plan, sub_plan)
+                            else:
+                                step_fn = get_step(sub_plan, sub_np)
+                        with spans.span(spans.PUT, n):
+                            sub_b = jax.tree.map(jnp.asarray, sub_np)
+                        with spans.span(spans.LR, n):
+                            lr = warmup_cosine(samples, peak_lr=job.peak_lr,
+                                               min_lr=job.min_lr,
+                                               warmup_steps=warmup_samples,
+                                               total_steps=total_samples)
+                        with spans.span(spans.DISPATCH, n):
+                            params, opt_state, metrics = step_fn(
+                                params, opt_state, sub_b, lr)
+                        samples += sub_plan.global_batch
+                        with spans.span(spans.READBACK, n):
+                            sub_losses.append(float(metrics["loss"]))
+                            if m == repeats - 1:
+                                # the last sub-step's var_l1 sits on the
+                                # sub-batch scale (E[var_l1] ≈ trΣ·J/b):
+                                # rescale to the scheduled plan's batch so
+                                # the controller sees the accumulated-path
+                                # scale
+                                var_l1 = (float(metrics["var_l1"])
+                                          * sub_plan.global_batch
+                                          / plan.global_batch)
+                                gsq = float(metrics["grad_sqnorm"])
+                    loss = float(np.mean(sub_losses))
+                    exec_plan, opt_steps = sub_plan, repeats
+                else:
+                    with spans.span(spans.LOOKUP, n):
                         if engine is not None:
-                            # (1, J·mb) is on the ladder by construction
-                            # (the accum-free rungs appended above)
-                            step_fn = engine.get_step(sub_np)
-                            engine.observe(sub_plan, sub_plan)
-                        sub_b = jax.tree.map(jnp.asarray, sub_np)
+                            step_fn = engine.get_step(batch_np)
+                            engine.observe(plan, bucket)
+                        else:
+                            step_fn = get_step(plan, batch_np)
+                    with spans.span(spans.PUT, n):
+                        batch = jax.tree.map(jnp.asarray, batch_np)
+                    with spans.span(spans.LR, n):
                         lr = warmup_cosine(samples, peak_lr=job.peak_lr,
                                            min_lr=job.min_lr,
                                            warmup_steps=warmup_samples,
                                            total_steps=total_samples)
-                        if engine is None:
-                            step_fn = get_step(sub_plan, sub_b)
-                        params, opt_state, metrics = step_fn(
-                            params, opt_state, sub_b, lr)
-                        samples += sub_plan.global_batch
-                        sub_losses.append(float(metrics["loss"]))
-                    loss = float(np.mean(sub_losses))
-                    # the last sub-step's var_l1 sits on the sub-batch scale
-                    # (E[var_l1] ≈ trΣ·J/b): rescale to the scheduled plan's
-                    # batch so the controller sees the accumulated-path scale
-                    var_l1 = (float(metrics["var_l1"])
-                              * sub_plan.global_batch / plan.global_batch)
-                    gsq = float(metrics["grad_sqnorm"])
-                    exec_plan, opt_steps = sub_plan, repeats
-                else:
-                    if engine is not None:
-                        batch_np = pad_to_bucket(batch_np, plan, bucket)
-                        step_fn = engine.get_step(batch_np)
-                        engine.observe(plan, bucket)
-                    batch = jax.tree.map(jnp.asarray, batch_np)
-                    lr = warmup_cosine(samples, peak_lr=job.peak_lr,
-                                       min_lr=job.min_lr,
-                                       warmup_steps=warmup_samples,
-                                       total_steps=total_samples)
-                    if engine is None:
-                        step_fn = get_step(plan, batch)
-                    params, opt_state, metrics = step_fn(params, opt_state,
-                                                         batch, lr)
-                    var_l1 = float(metrics["var_l1"])
-                    gsq = float(metrics["grad_sqnorm"])
-                    loss = float(metrics["loss"])
+                    with spans.span(spans.DISPATCH, n):
+                        params, opt_state, metrics = step_fn(params, opt_state,
+                                                             batch, lr)
+                    with spans.span(spans.READBACK, n):
+                        var_l1 = float(metrics["var_l1"])
+                        gsq = float(metrics["grad_sqnorm"])
+                        loss = float(metrics["loss"])
                     samples += plan.global_batch
                     exec_plan, opt_steps = plan, 1
                 step += 1
                 # this step's wall time, fenced on the updated state (input
                 # assembly and dispatch included; controller and eval not)
-                jax.block_until_ready((params, opt_state))
+                with spans.span(spans.FENCE, n):
+                    jax.block_until_ready((params, opt_state))
                 history["step_s"].append(time.time() - t_step)
+                history["lookup_wait_s"].append(
+                    0.0 if engine is None
+                    else engine.stats.lookup_wait_s - wait_before)
                 if job.schedule == "adaptive":
-                    ctrl = controller_update(ctrl_cfg, ctrl, var_l1, gsq)
+                    with spans.span(spans.CONTROLLER, n):
+                        ctrl = controller_update(ctrl_cfg, ctrl, var_l1, gsq)
                 if engine is not None:
                     # warmup AFTER the controller decision (DESIGN §14): warm
                     # the rung the fleet is actually headed to — the
@@ -541,7 +572,9 @@ def run_training(job: TrainJob) -> dict:
                                 ctrl.plan.global_batch)
                         elif job.predict and ctrl.pred_rung > bucket.global_batch:
                             proposal = engine.bucket_for(ctrl.pred_rung)
-                    engine.warmup_agreed(bucket, batch_np, proposal=proposal)
+                    with spans.span(spans.WARMUP, n):
+                        engine.warmup_agreed(bucket, batch_np,
+                                             proposal=proposal)
 
                 val = math.nan
                 if job.eval_every and (step % job.eval_every == 0

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from repro import spans
 from repro.models import attention as attn_lib
 from repro.models import mla as mla_lib
 from repro.models import rglru as rglru_lib
@@ -66,10 +67,11 @@ def _mixer_full(params, x, positions, cfg: ModelConfig, kind: str,
     rope_theta = cfg.rope_theta if cfg.pos_embed == "rope" else 0.0
     if kind in (ATTN, LOCAL_ATTN):
         window = cfg.sliding_window if kind == LOCAL_ATTN else 0
-        out = attn_lib.attend_full(
-            params["attn"], x, positions, rope_theta=rope_theta,
-            softcap=cfg.attn_logit_softcap, window=window, causal=causal,
-            qk_norm=cfg.qk_norm)
+        with jax.named_scope(spans.ATTENTION):
+            out = attn_lib.attend_full(
+                params["attn"], x, positions, rope_theta=rope_theta,
+                softcap=cfg.attn_logit_softcap, window=window, causal=causal,
+                qk_norm=cfg.qk_norm)
         if collect_cache:
             q, k, v = attn_lib._project_qkv(
                 params["attn"], x, positions, rope_theta, cfg.qk_norm)

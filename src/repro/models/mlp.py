@@ -5,6 +5,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.models.common import normal_init, split_keys
 from repro.distributed.sharding import maybe_shard
 
@@ -25,6 +26,7 @@ def init_mlp(key, d_model: int, d_ff: int, kind: str, dtype):
     raise ValueError(kind)
 
 
+@spans.scoped(spans.MLP)
 def apply_mlp(params, x, kind: str):
     if kind in ("swiglu", "geglu"):
         gate = jnp.einsum("btd,df->btf", x, params["w_gate"].astype(x.dtype))

@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.models import blocks as blk
 from repro.models.common import split_keys
 from repro.models.config import ModelConfig
@@ -177,6 +178,7 @@ def _xent(logits, labels):
     return jnp.sum(nll), jnp.sum(mask)
 
 
+@spans.scoped(spans.HEAD)
 def token_loss(params, hidden, labels, cfg: ModelConfig):
     """Chunked softmax cross-entropy over the sequence axis."""
     chunk = cfg.xent_chunk
