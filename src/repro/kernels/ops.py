@@ -5,14 +5,15 @@ flag > `REPRO_PALLAS_INTERPRET` env override > backend auto-detect (compiled
 on TPU, interpreted elsewhere — this container is CPU-only; the kernels
 target TPU and are validated against ref.py in interpret mode).
 
-The `*_flat` entry points at the bottom are the DESIGN §9 hot-path dispatch:
-compiled Pallas on TPU, the fused-jnp reference otherwise (interpret-mode
-Pallas is a correctness tool, far too slow for the per-step tail).
+The `*_flat` entry points and `attention_core` at the bottom are the hot-path
+dispatch (DESIGN §9, §16): compiled Pallas on TPU, jnp otherwise
+(interpret-mode Pallas is a correctness tool, far too slow for a step).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -168,17 +169,70 @@ def adamw_flat(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay, c1, c2,
 
 
 def flat_dispatch_info() -> dict:
-    """Which implementation the DESIGN §9 flat hot-path tail dispatches to
-    on this process's backend.  Recorded in the `repro.analysis` report's
-    `checked` section: a clean static-analysis run thereby documents WHICH
-    backend's step graphs it certified (the compiled-Pallas TPU tail and
-    the fused-jnp CPU tail lower different equations)."""
+    """Which implementation the DESIGN §9 flat hot-path tail and the
+    attention core dispatch to on this process's backend.  Recorded in the
+    `repro.analysis` report's `checked` section: a clean static-analysis
+    run thereby documents WHICH backend's step graphs it certified (the
+    compiled-Pallas TPU tail and the fused-jnp CPU tail lower different
+    equations)."""
     return {
         "backend": jax.default_backend(),
         "flat_tail": "pallas-compiled" if _backend_is_tpu() else
                      "jnp-reference",
+        "attention": "pallas-flash" if _backend_is_tpu() else "jnp",
         "pallas_interpret_default": bool(_default_interpret()),
     }
+
+
+# ------------------------------------------------ attention core dispatch ----
+
+FLASH_BLOCKS = (1024, 512, 256, 128)   # q and kv block of the kernels
+
+
+def flash_block(t: int, s: int) -> int | None:
+    """The flash kernels' block for self-attention over `t` positions: the
+    largest of `FLASH_BLOCKS` that divides t; None where the kernels do not
+    apply (cross-attention, t == s not a multiple of 128)."""
+    if t != s:
+        return None
+    return next((b for b in FLASH_BLOCKS if t % b == 0), None)
+
+
+def _on_local_heads(fn, q, k, v):
+    """Run `fn(q, k, v)` on each device's batch and head shard of
+    (b, t, heads, d) operands.  GSPMD cannot partition a Mosaic call, so
+    where the context mesh has Auto axes the call is wrapped in a
+    `shard_map` manual over them: batch split over the data axes, heads
+    over `model` where both head counts divide, other axes replicated."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = () if mesh.empty else tuple(mesh.auto_axes)
+    if not auto:
+        return fn(q, k, v)
+    daxes = tuple(a for a in data_axes(mesh) if a in auto)
+    if q.shape[0] % math.prod(mesh.shape[a] for a in daxes):
+        daxes = ()
+    maxes = tuple(a for a in ("model",) if a in auto
+                  and q.shape[2] % mesh.shape[a] == 0
+                  and k.shape[2] % mesh.shape[a] == 0)
+    spec = P(daxes or None, None, maxes or None, None)
+    return jax.shard_map(fn, in_specs=(spec, spec, spec), out_specs=spec,
+                         axis_names=set(auto), check_vma=False)(q, k, v)
+
+
+def attention_core(q, k, v, *, causal: bool, window: int, softcap: float):
+    """Backend-dispatched softmax(q·kᵀ/√d)·v of q (b, t, h, d) over k/v
+    (b, s, kv_heads, d): the compiled Pallas flash kernels (forward and
+    backward) on TPU where `flash_block` gives a block, the jnp path of
+    `repro.models.attention` elsewhere (the CPU path, and the only one for
+    shapes the kernels do not take).  Traced inside the callers' jit."""
+    block = flash_block(q.shape[1], k.shape[1])
+    if _backend_is_tpu() and block is not None:
+        return _on_local_heads(functools.partial(
+            _flash_attention, causal=causal, window=window, softcap=softcap,
+            block_q=block, block_kv=block), q, k, v)
+    # imported here: `repro.models.attention` imports this module
+    from repro.models.attention import sdpa_self
+    return sdpa_self(q, k, v, causal=causal, window=window, softcap=softcap)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))

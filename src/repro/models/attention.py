@@ -6,9 +6,12 @@ Three entry points:
   * caches: `init_cache` (full-length) and ring-buffer sliding caches for the
     `long_500k` serving mode.
 
-The pure-jnp path here is the reference; the Pallas flash kernel in
-`repro.kernels.flash_attention` is the TPU drop-in for the same math and is
-validated against this implementation in tests.
+`attend_full`'s score-softmax-output core goes through
+`repro.kernels.ops.attention_core`: the Pallas flash kernels
+(`repro.kernels.flash_attention`, forward and backward) on TPU, and the jnp
+path here (`sdpa_self`) on CPU and for shapes the kernels do not take.  The
+tests check the kernels against this path; the benchmark's plain reference
+is `benchmarks/chip/chipbench/reference/`.
 """
 
 from __future__ import annotations
@@ -16,6 +19,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import spans
+from repro.kernels import ops
 from repro.models.common import normal_init, split_keys
 from repro.models.embeddings import apply_rope
 from repro.distributed.sharding import maybe_shard
@@ -118,8 +123,8 @@ CHUNK_THRESHOLD = 2048  # use q-chunked (flash-style) attention for t >= this
 
 def _sdpa_chunked(q, k, v, softcap, causal, window, q_chunk=Q_CHUNK):
     """Memory-bounded attention: lax.scan over query chunks so the logits
-    buffer is O(q_chunk · s) instead of O(t · s).  This is the jnp-level
-    equivalent of the Pallas flash kernel (which replaces it on real TPU)."""
+    buffer is O(q_chunk · s) instead of O(t · s): the CPU path, and the TPU
+    path for shapes the Pallas flash kernels do not take."""
     b, t, h, dk = q.shape
     s = k.shape[1]
     assert t % q_chunk == 0, (t, q_chunk)
@@ -137,16 +142,23 @@ def _sdpa_chunked(q, k, v, softcap, causal, window, q_chunk=Q_CHUNK):
     return jnp.moveaxis(out, 0, 1).reshape(b, t, h, dk)
 
 
+def sdpa_self(q, k, v, *, causal, window, softcap):
+    """The jnp attention core of `attend_full`: q-chunked from
+    CHUNK_THRESHOLD positions on, else one score matrix."""
+    t = q.shape[1]
+    if t >= CHUNK_THRESHOLD and t % Q_CHUNK == 0:
+        return _sdpa_chunked(q, k, v, softcap, causal, window)
+    mask = causal_mask(t, t, 0, window) if causal else None
+    return _sdpa(q, k, v, mask, softcap)
+
+
 def attend_full(params, x, positions, *, rope_theta, softcap=0.0, window=0,
                 causal=True, qk_norm=False):
     """Self-attention over a full sequence (training / prefill)."""
     q, k, v = _project_qkv(params, x, positions, rope_theta, qk_norm)
-    t = x.shape[1]
-    if t >= CHUNK_THRESHOLD and t % Q_CHUNK == 0:
-        out = _sdpa_chunked(q, k, v, softcap, causal, window)
-    else:
-        mask = causal_mask(t, t, 0, window) if causal else None
-        out = _sdpa(q, k, v, mask, softcap)
+    with jax.named_scope(spans.ATTENTION_CORE):
+        out = ops.attention_core(q, k, v, causal=causal, window=window,
+                                 softcap=softcap)
     out = jnp.einsum("bthk,hkd->btd", out, params["wo"].astype(x.dtype))
     return maybe_shard(out, "batch", "seq", "embed")
 

@@ -3,7 +3,7 @@
 The training loop wraps each phase of a step in a host span
 (`jax.profiler.TraceAnnotation`) named here, with the 1-based step as its
 `step` argument; the model wraps its three big blocks in
-`jax.named_scope`s.
+`jax.named_scope`s, and the attention core in one more inside `attention`.
 All of them land in the JAX profiler's own trace, on the device trace's
 clock, and cost one level check each while no trace is active.
 """
@@ -32,6 +32,9 @@ ATTENTION = "attention"
 MLP = "mlp"
 HEAD = "head"
 SCOPES = (ATTENTION, MLP, HEAD)
+# nested in ATTENTION: the score-softmax-output core (`ops.attention_core`),
+# without the projections and RoPE
+ATTENTION_CORE = "attention_core"
 
 
 def span(name: str, step: int):

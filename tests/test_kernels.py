@@ -85,28 +85,94 @@ def test_rmsnorm_sweep(rows, d, dtype):
                                atol=2e-2 if dtype == jnp.bfloat16 else 1e-5)
 
 
-@pytest.mark.parametrize("b,t,h,kvh,d,causal,window,softcap", [
+FLASH_CASES = [
     (2, 256, 4, 2, 64, True, 0, 0.0),
     (1, 512, 4, 4, 64, True, 128, 0.0),
     (2, 256, 8, 2, 32, True, 0, 50.0),       # gemma2-style softcap
     (1, 256, 2, 2, 64, False, 0, 0.0),        # encoder (bidirectional)
     (1, 384, 4, 1, 64, True, 256, 30.0),      # MQA + window + cap
-])
+]
+FLASH_ARGS = "b,t,h,kvh,d,causal,window,softcap"
+FLASH_TOL = {jnp.float32: 3e-5, jnp.bfloat16: 3e-2}
+
+
+def _qkv(b, t, h, kvh, d, dtype):
+    k1, k2, k3 = jax.random.split(KEY, 3)
+    return (jax.random.normal(k1, (b, t, h, d)).astype(dtype),
+            jax.random.normal(k2, (b, t, kvh, d)).astype(dtype),
+            jax.random.normal(k3, (b, t, kvh, d)).astype(dtype))
+
+
+def _assert_close(got, want, tol):
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=tol,
+                               atol=tol)
+
+
+@pytest.mark.parametrize(FLASH_ARGS, FLASH_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_sweep(b, t, h, kvh, d, causal, window, softcap, dtype):
-    k1, k2, k3 = jax.random.split(KEY, 3)
-    q = jax.random.normal(k1, (b, t, h, d)).astype(dtype)
-    k = jax.random.normal(k2, (b, t, kvh, d)).astype(dtype)
-    v = jax.random.normal(k3, (b, t, kvh, d)).astype(dtype)
+    q, k, v = _qkv(b, t, h, kvh, d, dtype)
     got = ops.flash_attention(q, k, v, causal=causal, window=window,
                               softcap=softcap, block_q=128, block_kv=128)
     kx = jnp.repeat(k, h // kvh, axis=2)
     vx = jnp.repeat(v, h // kvh, axis=2)
     want = ref.attention_ref(q, kx, vx, causal=causal, window=window,
                              softcap=softcap)
-    tol = 3e-2 if dtype == jnp.bfloat16 else 3e-5
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), rtol=tol, atol=tol)
+    _assert_close(got, want, FLASH_TOL[dtype])
+
+
+def _sdpa_core(q, k, v, causal, window, softcap):
+    from repro.models.attention import _sdpa, causal_mask
+    t = q.shape[1]
+    mask = causal_mask(t, t, 0, window) if causal else None
+    return _sdpa(q, k, v, mask, softcap)
+
+
+@pytest.mark.parametrize(FLASH_ARGS, FLASH_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grad_sweep(b, t, h, kvh, d, causal, window, softcap,
+                                    dtype):
+    """The kernels' backward (dK/dV summed over each kv head's query
+    group, dQ) against autodiff through the model's jnp `_sdpa`."""
+    q, k, v = _qkv(b, t, h, kvh, d, dtype)
+    w = jax.random.normal(jax.random.PRNGKey(3), q.shape).astype(dtype)
+
+    def loss(core):
+        return lambda q, k, v: jnp.sum(
+            core(q, k, v).astype(jnp.float32) * w.astype(jnp.float32))
+
+    got = jax.grad(loss(lambda q, k, v: ops.flash_attention(
+        q, k, v, causal=causal, window=window, softcap=softcap, block_q=128,
+        block_kv=128)), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: _sdpa_core(
+        q, k, v, causal, window, softcap)), (0, 1, 2))(q, k, v)
+    for g, x in zip(got, (q, k, v)):
+        assert g.dtype == x.dtype and g.shape == x.shape
+    for a, b_ in zip(got, want):
+        _assert_close(a, b_, FLASH_TOL[dtype])
+
+
+@pytest.mark.parametrize(FLASH_ARGS, FLASH_CASES)
+def test_flash_attention_lse_residual(b, t, h, kvh, d, causal, window,
+                                      softcap):
+    """The forward's logsumexp residual is the log-sum-exp of the scaled,
+    capped, masked scores."""
+    from repro.kernels import flash_attention as fa
+    from repro.models.attention import causal_mask
+    q, k, v = _qkv(b, t, h, kvh, d, jnp.float32)
+    qf, kf, vf, c = fa._flat(q, k, v, causal, window, softcap, 128, 128,
+                             None)
+    _, lse = fa._forward(*fa._operands(qf, kf, vf, c), c)
+    got = lse.reshape(b, h, t)
+    kx = jnp.repeat(k, h // kvh, axis=2)
+    logits = jnp.einsum("bthd,bshd->bhts", q, kx) / jnp.sqrt(d)
+    if softcap > 0:
+        logits = softcap * jnp.tanh(logits / softcap)
+    if causal:
+        logits = jnp.where(causal_mask(t, t, 0, window), logits, -jnp.inf)
+    _assert_close(got, jax.nn.logsumexp(logits, axis=-1),
+                  FLASH_TOL[jnp.float32])
 
 
 def test_flash_attention_matches_model_attention():
@@ -121,3 +187,80 @@ def test_flash_attention_matches_model_attention():
     got = ops.flash_attention(q, k, v, causal=True, block_q=128, block_kv=128)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-5, atol=3e-5)
+
+
+def _attend_case(t=256, h=4, kvh=2, d=32):
+    from repro.models.attention import init_attention
+    params = init_attention(jax.random.PRNGKey(1), 64, h, kvh, d, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, t, 64))
+    return params, x, jnp.arange(t)[None, :]
+
+
+@pytest.mark.parametrize("causal,window,softcap", [
+    (True, 0, 0.0), (True, 128, 30.0), (False, 0, 0.0)])
+def test_attend_full_dispatch(monkeypatch, causal, window, softcap):
+    """`attend_full` takes the flash kernels where the backend is TPU
+    (here in interpret mode) and the jnp path on CPU, with the same
+    values and gradients; `flat_dispatch_info` reports which."""
+    from repro.models.attention import attend_full
+    params, x, pos = _attend_case()
+
+    def run():
+        def loss(params, x):      # new per run: traces are cached by function
+            out = attend_full(params, x, pos, rope_theta=1e4, softcap=softcap,
+                              window=window, causal=causal)
+            return jnp.sum(out * jnp.cos(out))
+        jaxpr = str(jax.make_jaxpr(loss)(params, x))
+        val, grads = jax.value_and_grad(loss, (0, 1))(params, x)
+        return "pallas_call" in jaxpr, val, grads
+
+    assert ops.flat_dispatch_info()["attention"] == "jnp"
+    on_jnp, val_j, grads_j = run()
+    monkeypatch.setattr(ops, "_backend_is_tpu", lambda: True)
+    assert ops.flat_dispatch_info()["attention"] == "pallas-flash"
+    on_kernel, val_k, grads_k = run()
+    assert on_kernel and not on_jnp
+    _assert_close(val_k, val_j, 1e-4)
+    for a, b in zip(jax.tree.leaves(grads_k), jax.tree.leaves(grads_j)):
+        _assert_close(a, b, FLASH_TOL[jnp.float32])
+
+
+@pytest.mark.parametrize("t,s,block", [
+    (2048, 2048, 1024), (1536, 1536, 512), (384, 384, 128), (640, 640, 128),
+    (200, 200, None), (256, 512, None)])
+def test_flash_block(t, s, block):
+    """The kernels take self-attention whose length a block divides."""
+    assert ops.flash_block(t, s) == block
+
+
+def test_attention_core_on_a_mesh(subproc):
+    """On a 2x2 (data, model) mesh of Auto axes the kernels run on each
+    device's batch and head shard (GSPMD cannot partition a Mosaic call)
+    and agree with the jnp path."""
+    out = subproc("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType, Mesh
+from repro.kernels import ops
+ks = jax.random.split(jax.random.PRNGKey(0), 4)
+q = jax.random.normal(ks[0], (2, 256, 4, 32))
+k, v = (jax.random.normal(kk, (2, 256, 2, 32)) for kk in ks[1:3])
+w = jax.random.normal(ks[3], q.shape)
+mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
+def loss(q, k, v):
+    out = ops.attention_core(q, k, v, causal=True, window=0, softcap=0.0)
+    return jnp.sum(out * w)
+f = jax.jit(jax.value_and_grad(loss, (0, 1, 2)))
+with jax.set_mesh(mesh):
+    want = f(q, k, v)
+    ops._backend_is_tpu = lambda: True
+    g = jax.jit(jax.value_and_grad(lambda *a: loss(*a), (0, 1, 2)))
+    jaxpr = str(jax.make_jaxpr(g)(q, k, v))
+    got = g(q, k, v)
+assert "pallas_call" in jaxpr and "shard_map" in jaxpr, jaxpr
+for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=3e-5,
+                               atol=3e-5)
+print("OK")
+""", devices=4)
+    assert "OK" in out

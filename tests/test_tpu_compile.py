@@ -23,6 +23,8 @@ from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 V5E_HBM_BYTES = 15.75e9        # usable HBM of one v5e chip (16 GB part)
 BUCKET = (4 << 20) // 4        # one 4 MiB f32 flat bucket
+FLASH_KERNELS = ("flash_attention_fwd", "flash_attention_dkv",
+                 "flash_attention_dq")
 
 
 @pytest.fixture(scope="module")
@@ -82,27 +84,37 @@ def _kernel_case(name, sds):
     if name == "rmsnorm":
         return (lambda x, s: rmsnorm(x, s, interpret=False),
                 (sds((4 * 2048, 1024)), sds((1024,))))
-    assert name == "flash_attention"
-    qkv = sds((4, 2048, 16, 64))
-    return (lambda q, k, v: flash_attention(q, k, v, interpret=False),
-            (qkv, qkv, qkv))
+    if name == "flash_attention":
+        qkv = sds((4, 2048, 16, 64))
+        return (lambda q, k, v: flash_attention(q, k, v, interpret=False),
+                (qkv, qkv, qkv))
+    # the forward and both backward kernels at the Phi-3-mini cell's
+    # attention: 32 heads of 96, seq 2048, micro-batch 2
+    assert name == "flash_attention_bwd"
+    qkv = sds((2, 2048, 32, 96))
+    return (jax.grad(lambda q, k, v, w: jnp.sum(w * flash_attention(
+        q, k, v, interpret=False)), (0, 1, 2)), (qkv,) * 4)
 
 
 @pytest.mark.parametrize("name", ["fused_stats", "sqdiff_norm",
                                   "fused_adamw_stats", "rmsnorm",
-                                  "flash_attention"])
+                                  "flash_attention", "flash_attention_bwd"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                              sharding=one_chip)
     fn, args = _kernel_case(name, sds)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if name == "flash_attention_bwd":
+        for kernel in FLASH_KERNELS:
+            assert kernel in compiled.as_text(), kernel
 
 
 def _compile_step(topo, arch, step_impl, stats_impl, params_impl, *,
-                  workers, accum, micro, seq=2048):
-    """Compile one full-width train step (remat full) on a `workers`-chip
-    data mesh of the described topology; returns the compiled step."""
+                  workers, accum, micro, seq=2048, layers=None):
+    """Compile one full-width train step (remat full; `layers` deep where
+    given) on a `workers`-chip data mesh of the described topology;
+    returns the compiled step."""
     from repro.configs import get_config
     from repro.distributed.train_step import (
         _opt_like_for, make_accum_norm_step, make_fsdp_norm_step)
@@ -110,7 +122,9 @@ def _compile_step(topo, arch, step_impl, stats_impl, params_impl, *,
     from repro.optim.adamw import AdamWConfig
     mesh = Mesh(np.array(topo.devices[:workers]).reshape(workers, 1),
                 ("data", "model"), axis_types=(AxisType.Auto,) * 2)
-    model = build_model(get_config(arch).replace(remat="full"))
+    cfg = get_config(arch).replace(remat="full")
+    model = build_model(cfg if layers is None else cfg.replace(
+        num_layers=layers))
     params_like = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     build = (make_accum_norm_step if step_impl == "accum_norm"
              else make_fsdp_norm_step)
@@ -144,6 +158,20 @@ def test_full_width_accum_norm_step_fits_one_v5e(topo, as_tpu):
     assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
 
 
+def test_phi3_mini_step_runs_flash_attention(topo, as_tpu):
+    """The benchmark's Phi-3-mini step (3 layers at published widths,
+    ACCUM-NORM M=4 x micro 2, seq 2048) runs attention in the flash
+    kernels, forward and backward: no (2, 32, 512, 2048) f32 score chunk
+    of the jnp path is left, and the step fits one chip."""
+    compiled = _compile_step(topo, "phi3-mini-3.8b", "accum_norm", "flat",
+                             "flat", workers=1, accum=4, micro=2, layers=3)
+    text = compiled.as_text()
+    for kernel in FLASH_KERNELS:
+        assert kernel in text, kernel
+    assert "f32[2,32,512,2048]" not in text
+    assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
+
+
 @pytest.mark.parametrize("step_impl", ["fsdp_norm", "accum_norm"])
 def test_four_chip_flat_tail_step_compiles(topo, as_tpu, step_impl):
     """J=4 data mesh, flat/flat: the Pallas tail lowers inside the
@@ -163,6 +191,9 @@ def test_pallas_tail_makes_no_relayout_copies(topo, as_tpu, monkeypatch):
     TPU; through such views this step needed 25% more, and TinyLlama-1.1B's
     at micro-batch 2 did not fit a chip."""
     import repro.kernels.ops
+    from repro.models.attention import sdpa_self
+    # attention on the jnp path in both steps: the tail alone differs
+    monkeypatch.setattr(repro.kernels.ops, "attention_core", sdpa_self)
     kw = dict(workers=4, accum=1, micro=2)
     pallas = _compile_step(topo, "microllama-300m", "fsdp_norm", "flat",
                            "flat", **kw)
