@@ -93,27 +93,6 @@ def train_job(config: dict, traffic: dict, seed: int, steps: int, chips: int):
     return TrainJob(**fields)
 
 
-def model_sizes(config: dict) -> dict:
-    m = dict(config["model"])
-    m["head_dim"] = m["hidden_size"] // m["num_attention_heads"]
-    return m
-
-
-def check_program_config(cfg, m: dict):
-    """The trainer's ModelConfig must be the file's model, width for width."""
-    pairs = {"d_model": "hidden_size", "d_ff": "intermediate_size",
-             "num_heads": "num_attention_heads",
-             "num_kv_heads": "num_key_value_heads",
-             "num_layers": "num_hidden_layers", "vocab_size": "vocab_size",
-             "head_dim": "head_dim", "rope_theta": "rope_theta"}
-    bad = {a: (getattr(cfg, a), m[b]) for a, b in pairs.items()
-           if getattr(cfg, a) != m[b]}
-    if cfg.tie_embeddings != m["tie_word_embeddings"]:
-        bad["tie_embeddings"] = (cfg.tie_embeddings, m["tie_word_embeddings"])
-    if bad:
-        raise ValueError(f"the trainer's model differs from the file: {bad}")
-
-
 class Hooks:
     """What the benchmark does at the trainer's step boundaries."""
 
@@ -297,12 +276,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     from repro.configs import get_config
     from repro.launch.train import run_training
 
-    m = model_sizes(config)
+    ref = spec.reference_module(config["reference"])
+    m = ref.sizes(config["model"])
     plan = plan_steps(cell, traffic, seconds)
     job = train_job(config, traffic, seed, plan["total"], chips)
     model_cfg = get_config(config["program"]["arch"]).replace(
         **config["program"].get("overrides", {}))
-    check_program_config(model_cfg, m)
+    ref.check_program(model_cfg, m)
     hooks = Hooks(plan["setup"], plan["total"], TRACE_DIR if trace else None,
                   fault)
     restore = install(hooks, model_cfg)
@@ -316,6 +296,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     seq = traffic["seq_len"]
     tokens = sum(hist["global_batch"][k:]) * seq
     peak_bytes = memory_peak(devices)
+    log(f"reference {config['reference']}: {ref.param_count(m)} "
+        f"parameters, {ref.flops_per_token(m, traffic['seq_len'])!r} "
+        f"operations per token")
     log(f"steps {plan} global batch {hist['global_batch']}")
     log(f"step_s {[round(s, 4) for s in hist['step_s']]}")
     log(f"T {hist['T']}")
@@ -330,16 +313,16 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     # ---- the comparison, after the window and with the trainer's state gone
     rungs = traffic["rungs"]
     t_ref = time.time()  # repro: allow(unfenced-timing) — the reference returns host floats
-    ref = reference(config, traffic, chips, seed, k, plan["total"],
-                    prog_params=hooks.params_after)
-    nums = compare.numbers(hist, hooks.first_m_norms, ref,
+    ref_run = reference(config, traffic, chips, seed, k, plan["total"],
+                        prog_params=hooks.params_after)
+    nums = compare.numbers(hist, hooks.first_m_norms, ref_run,
                            config["optimizer"]["beta1"], traffic["schedule"],
                            rungs, traffic["eta"], traffic["test_interval"])
     ok, lines = compare.verdict(nums, cell["limits"])
     log(f"reference s {time.time() - t_ref:.1f}")
-    log(f"reference loss {ref.loss} trainer loss {hist['loss'][:k]}")
-    log(f"reference var_l1 {ref.var_l1} trainer {hist['var_l1'][:k]}")
-    log(f"reference grad_sqnorm {ref.grad_sqnorm} trainer "
+    log(f"reference loss {ref_run.loss} trainer loss {hist['loss'][:k]}")
+    log(f"reference var_l1 {ref_run.var_l1} trainer {hist['var_l1'][:k]}")
+    log(f"reference grad_sqnorm {ref_run.grad_sqnorm} trainer "
         f"{hist['grad_sqnorm'][:k]}")
 
     d = devices[0]
@@ -354,7 +337,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
                 metrics[mm["name"]] = {"value": e2e[mm["name"]],
                                        "unit": mm["unit"]}
     else:
-        ctx = layer_context(hist, hooks, plan, config, traffic, m, devices)
+        ctx = layer_context(hist, hooks, plan, config, traffic, ref, m,
+                            devices)
         device["busy_s"] = ctx.busy_s
         device["window_s"] = ctx.window_s
         for mm in r["metrics"]["per_layer"]:
@@ -384,20 +368,23 @@ def optimizer(config: dict) -> dict:
 
 def reference(config: dict, traffic: dict, chips: int, seed: int,
               steps: int, total_steps: int, **kw):
-    """The plain reference over the run's first `steps` steps, at the rungs
-    its own norm test prescribes; `kw` goes to `reference.train.run`
-    (precision, faults, the trainer's weights)."""
+    """The configuration's plain reference over the run's first `steps`
+    steps, at the rungs its own norm test prescribes, its state spread over
+    the cell's chips; `kw` goes to `reference.train.run` (precision,
+    faults, the trainer's weights)."""
+    import jax
     from chipbench.reference import train as ref_train
-    if config["train"]["step_impl"] != "accum_norm":
-        raise ValueError("the reference follows ACCUM-NORM steps only, not "
-                         f"{config['train']['step_impl']!r}")
+    ref = spec.reference_module(config["reference"])
     rungs = traffic["rungs"]
     return ref_train.run(
-        model_sizes(config), optimizer(config), seed=seed, data_seed=seed,
-        steps=steps, rows=chips * traffic["micro_batch"], workers=chips,
-        seq_len=traffic["seq_len"], total_samples=total_steps * rungs[-1],
-        eta=traffic["eta"], rungs=rungs, schedule=traffic["schedule"],
-        test_interval=traffic["test_interval"], **kw)
+        ref, ref.sizes(config["model"]), optimizer(config), seed=seed,
+        data_seed=seed, steps=steps, rows=chips * traffic["micro_batch"],
+        workers=chips, seq_len=traffic["seq_len"],
+        total_samples=total_steps * rungs[-1], eta=traffic["eta"],
+        rungs=rungs, schedule=traffic["schedule"],
+        test_interval=traffic["test_interval"],
+        step_impl=config["train"]["step_impl"],
+        devices=jax.devices()[:chips], **kw)
 
 
 @dataclasses.dataclass
@@ -422,7 +409,7 @@ class LayerContext:
     idle_gaps: list
 
 
-def layer_context(hist, hooks, plan, config, traffic, m, devices
+def layer_context(hist, hooks, plan, config, traffic, ref, m, devices
                   ) -> LayerContext:
     events = tr.load_xplane(TRACE_DIR)
     lo, hi = tr.window(events)
@@ -430,13 +417,14 @@ def layer_context(hist, hooks, plan, config, traffic, m, devices
     ops = {i: tr.device_ops(events, i, lo, hi) for i in ids}
     busy = [tr.busy_ns(ops[i]) * 1e-9 for i in ids]
     k = plan["setup"]
-    n = counts.param_count(m)
+    n = ref.param_count(m)
     chips = len(devices)
     ctx = LayerContext(
         history=hist, setup_steps=k, seq_len=traffic["seq_len"],
         chips=chips, model=m, peak=peaks.peaks_for(devices[0].device_kind),
-        flops_per_token=counts.flops_per_token(m, traffic["seq_len"]),
-        tail_bytes_per_step=counts.tail_bytes_per_step(n, chips),
+        flops_per_token=ref.flops_per_token(m, traffic["seq_len"]),
+        tail_bytes_per_step=counts.tail_bytes_per_step(
+            n, chips, config["train"]["step_impl"]),
         events=events, window_s=(hi - lo) * 1e-9, window_ns=(lo, hi),
         device_ops=ops,
         busy_s=statistics.mean(busy) if busy else 0.0,

@@ -17,6 +17,10 @@ decoupled weight decay.
 Departures from the published Phi-3-mini, each as the trainer runs it:
 RMSNorm epsilon 1e-6 (published 1e-5); no attention window (published
 2047, which at 2048 positions masks only key 0 for the last query).
+
+The module is a configuration's `"reference"` (see the package's doc): it
+also derives the sizes, checks the trainer's model against them and counts
+the parameters and operations.
 """
 
 from __future__ import annotations
@@ -26,6 +30,58 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+def sizes(model: dict) -> dict:
+    """The file's `model`, with the head size the config leaves implicit."""
+    m = dict(model)
+    m["head_dim"] = m["hidden_size"] // m["num_attention_heads"]
+    return m
+
+
+def check_program(cfg, m: dict):
+    """The trainer's ModelConfig must be the file's model, width for width."""
+    pairs = {"d_model": "hidden_size", "d_ff": "intermediate_size",
+             "num_heads": "num_attention_heads",
+             "num_kv_heads": "num_key_value_heads",
+             "num_layers": "num_hidden_layers", "vocab_size": "vocab_size",
+             "head_dim": "head_dim", "rope_theta": "rope_theta"}
+    bad = {a: (getattr(cfg, a), m[b]) for a, b in pairs.items()
+           if getattr(cfg, a) != m[b]}
+    if cfg.tie_embeddings != m["tie_word_embeddings"]:
+        bad["tie_embeddings"] = (cfg.tie_embeddings, m["tie_word_embeddings"])
+    if bad:
+        raise ValueError(f"the trainer's model differs from the file: {bad}")
+
+
+def layer_matmul_params(m: dict) -> int:
+    """Weights of one decoder layer that enter a matrix product."""
+    d, h, kv, dh, ff = (m["hidden_size"], m["num_attention_heads"],
+                        m["num_key_value_heads"], m["head_dim"],
+                        m["intermediate_size"])
+    attn = d * h * dh + 2 * d * kv * dh + h * dh * d
+    mlp = 3 * d * ff if m["hidden_act"] == "silu" else 2 * d * ff
+    return attn + mlp
+
+
+def param_count(m: dict) -> int:
+    """Every parameter: embedding, layers (with their two norm scales),
+    final norm and (untied) output head."""
+    d, v, n = m["hidden_size"], m["vocab_size"], m["num_hidden_layers"]
+    head = 0 if m.get("tie_word_embeddings") else v * d
+    return v * d + head + n * (layer_matmul_params(m) + 2 * d) + d
+
+
+def flops_per_token(m: dict, seq_len: int) -> float:
+    """Forward plus backward operations per trained token, recomputation not
+    counted: 6 per weight of every matrix product (layers and output head;
+    the embedding is a lookup) and causal attention's two products, whose
+    query at position i meets i + 1 keys, (seq_len + 1) / 2 on average."""
+    d, v, n = m["hidden_size"], m["vocab_size"], m["num_hidden_layers"]
+    h, dh = m["num_attention_heads"], m["head_dim"]
+    matmul = n * layer_matmul_params(m) + v * d
+    attn_fwd = n * 2 * 2 * h * dh * (seq_len + 1) / 2
+    return 6.0 * matmul + 3.0 * attn_fwd
 
 
 def _normal(key, shape, dtype):
@@ -139,13 +195,13 @@ def loss_sum(params, tokens, labels, c):
 
 
 def block_grad(c):
-    """jit: (params, tokens, labels) -> (loss sum, valid count, grad of the
-    loss sum), all in the params' dtype."""
+    """(params, tokens, labels) -> (loss sum, valid count, grad of the loss
+    sum), all in the params' dtype; the caller jits it."""
     def f(params, tokens, labels):
         (s, n), g = jax.value_and_grad(
             lambda p: loss_sum(p, tokens, labels, c), has_aux=True)(params)
         return s, n, g
-    return jax.jit(f)
+    return f
 
 
 def sqnorm(tree) -> jax.Array:

@@ -1,16 +1,19 @@
 """`BENCHMARK.json` and the files it names, found by name.
 
 A cell `<config>.<traffic>` reads `configs/<config>.json`,
-`traffic/<traffic>.json` and `cells/<cell>.json`; a per-layer metric
-`<name>` is read by `metrics/<name>.py`.  Adding a configuration, a mix,
-a cell or a metric adds files and edits none.
+`traffic/<traffic>.json` and `cells/<cell>.json`; the configuration's
+`"reference"` names its plain reference, `chipbench/reference/<name>.py`;
+a per-layer metric `<name>` is read by `metrics/<name>.py`.  Adding a
+configuration, a mix, a cell or a metric adds files and edits none.
 """
 
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
+import re
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -63,6 +66,22 @@ def resolve(bench: dict, name: str) -> dict:
             "traffic": load_json(traffic_path(w["traffic"])),
             "cell": load_json(cell_path(name)),
             "metrics": metrics}
+
+
+def reference_module(name: str):
+    """The plain reference module a configuration names (see
+    `chipbench/reference/__init__.py` for what it provides)."""
+    full = f"chipbench.reference.{name}"
+    if not re.fullmatch(r"[a-z_][a-z0-9_]*", name) or name in (
+            "train", "markov"):
+        raise ValueError(f"{name!r} is no reference module name")
+    try:
+        return importlib.import_module(full)
+    except ModuleNotFoundError as e:
+        if e.name != full:
+            raise
+        raise ValueError(f"the configuration names reference {name!r}: "
+                         f"there is no chipbench/reference/{name}.py") from e
 
 
 def metric_reader(name: str):

@@ -3,11 +3,14 @@
     python3 benchmarks/chip/control.py --workload <cell> --seeds 1 2 3 \
         --variants bfloat16 half_batch
 
-Puts the plain reference in the trainer's place, computed a precision
+Puts the configuration's plain reference, following the cell's own step
+(ACCUM-NORM or FSDP-Norm), in the trainer's place, computed a precision
 lower (`bfloat16`, the control) or with a fault planted in its step
-(`half_batch`: half of each microbatch's rows left out, the mean taken over
-the rest), and compares
-it with the float32 reference exactly as a run compares the trainer.  The
+(`half_batch`: half of each worker's rows of every microbatch left out,
+the mean taken over the rest; `exchange`, on several workers: the last
+worker's rows replaced by the first's, so its contribution is missing from
+the reduced gradient), and compares it with the float32 reference exactly
+as a run compares the trainer.  The
 readings, one line per seed and variant, set the upper end of each
 number's limit (PERF.md).  Runs at the cell's own sizes on the machine it
 is started on; the benchmark's own runs never run it.
@@ -30,8 +33,9 @@ def control_numbers(resolved: dict, seed: int, variants: list[str],
                     seconds: float) -> dict:
     """Variant -> the numbers a run of `seconds` compares, with the variant
     in the trainer's place (one float32 reference for all of them)."""
+    import jax
     import jax.numpy as jnp
-    from chipbench import compare, harness
+    from chipbench import compare, harness, spec
     from chipbench.reference import train as ref_train
     config, traffic = resolved["config"], resolved["traffic"]
     chips = resolved["workload"]["chips"]
@@ -48,7 +52,10 @@ def control_numbers(resolved: dict, seed: int, variants: list[str],
                                  total_steps, keep_params=True, **kw)
         # the initial weights live on the device only beside the side's
         # host copy, never beside a reference run's working set
-        p0 = ref_train.initial_weights(harness.model_sizes(config), seed)
+        ref_mod = spec.reference_module(config["reference"])
+        p0 = ref_train.initial_weights(
+            ref_mod, ref_mod.sizes(config["model"]), seed,
+            jax.devices()[:chips])
         ref.prog_change = ref_train.change_from(p0, side.params)
         del p0
         hist = {"loss": side.loss, "var_l1": side.var_l1,
@@ -68,7 +75,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--variants", nargs="+", required=True,
-                    choices=("bfloat16", "half_batch"))
+                    choices=("bfloat16", "half_batch", "exchange"))
     args = ap.parse_args(argv)
     from chipbench import harness, spec
     bench = spec.benchmark()

@@ -1,19 +1,21 @@
 """The flat tail kernels' share of their HBM roofline on device 0: the
 bytes their algorithms must move over the traced steps (`counts`: AdamW
-reads p, g, m, v and writes p, m, v), at the chip's peak bandwidth, over
-the summed device time of those kernels' events.  Silent where no tail
-kernel ran.  Moves `train_tokens_per_s`.
+reads p, g, m, v and writes p, m, v; under FSDP-Norm the fused statistics
+read g_j and g), at the chip's peak bandwidth, over the summed device time
+of those kernels' events.  Silent where no tail kernel ran.  Moves
+`train_tokens_per_s`.
 
 The device trace names a Pallas kernel only as a `tpu_custom_call`; the
 tail's AdamW is the one whose four operands (p, g, m, v) are 1-D flat
-buffers."""
+buffers, the fused statistics the one whose two are.  A family the cell's
+step does not count (`ctx.tail_bytes_per_step`) is left out."""
 
 import re
 
 MOSAIC = 'custom_call_target="tpu_custom_call"'
 OPERANDS = re.compile(r"custom-call\((.*?)\), custom_call_target")
 FLAT = re.compile(r"\b(?:f32|bf16)\[\d+\]\{0")
-FAMILY_BY_FLAT_OPERANDS = {4: "adamw"}
+FAMILY_BY_FLAT_OPERANDS = {4: "adamw", 2: "stats"}
 
 
 def family(name):

@@ -84,6 +84,23 @@ def test_crossing_falls_among_the_compared_steps():
     assert ref.batch == [8, 32] and ref.decision == [32, 32]
 
 
+def test_traced_run_reaches_the_readers(monkeypatch):
+    """A `--trace 1` run on the CPU goes through the trace's reduction and
+    every reader of the cell; the CPU has no published peaks, so the
+    chip's stand in, and no number here is a device metric."""
+    from chipbench import peaks
+    monkeypatch.setattr(harness.peaks, "peaks_for",
+                        lambda kind: peaks.PEAKS["TPU v5 lite"])
+    r = chipbench_tiny.tiny(CELL)
+    out = harness.run_cell(CELL, 2147483693, 0.5, True, time.time(),
+                           resolved=r, require_tpu=False)
+    assert out["correct"], out["compared"]
+    assert out["device"]["window_s"] > 0 and "busy_s" in out["device"]
+    assert set(out["metrics"]) <= {m["name"] for m in
+                                   r["metrics"]["per_layer"]}
+    assert "step_mfu" in out["metrics"]
+
+
 def test_control_fails():
     """The reference a precision lower (bfloat16) in the trainer's place."""
     import control

@@ -57,6 +57,29 @@ def test_exposed_collective_is_what_no_compute_hides():
     assert tr.exposed_collective_ns([ev("fusion.1", 0, 5)]) is None
 
 
+def test_exposed_collective_share_is_the_mean_over_chips():
+    """Four chips, a 1000-ns window: chip 0's all-gather is exposed for
+    60 ns outside its fusion; chip 1's asynchronous all-reduce lies wholly
+    under compute and is not counted; chip 2's reduce-scatter is exposed
+    for 100 ns, half of it clipped by the window; chip 3 runs none."""
+    from types import SimpleNamespace
+    events = [ev(tr.WINDOW_MARK, 0, 1000, "/host:CPU", "python"),
+              ev("all-gather.1", 100, 100), ev("fusion.1", 140, 40),
+              ev("fusion.2", 100, 300, "/device:TPU:1"),
+              ev("all-reduce-start.3", 150, 200, "/device:TPU:1",
+                 "Async XLA Ops"),
+              ev("reduce-scatter.4", 900, 200, "/device:TPU:2"),
+              ev("fusion.5", 0, 50, "/device:TPU:3")]
+    lo, hi = tr.window(events)
+    ctx = SimpleNamespace(events=events, window_ns=(lo, hi), device_ops={
+        i: tr.device_ops(events, i, lo, hi) for i in range(4)})
+    read = spec.metric_reader("exposed_collective_share")
+    assert read(ctx) == pytest.approx(100.0 * (60 + 0 + 100 + 0) / 4 / 1000)
+    one_chip = SimpleNamespace(events=events, window_ns=(lo, hi),
+                               device_ops={3: ctx.device_ops[3]})
+    assert read(one_chip) is None
+
+
 def test_top_ops_and_idle_gaps_are_named():
     events = [ev(tr.WINDOW_MARK, 0, 1000, "/host:CPU", "python"),
               ev("fusion.1", 0, 100), ev("fusion.7", 400, 100),
