@@ -1,5 +1,6 @@
-"""Architecture registry: 10 assigned archs + the paper's 3 Llama-2-family
-experiment models.  `get_config(name)` / `get_smoke_config(name)`."""
+"""Architecture registry: 10 assigned archs, the paper's 3 Llama-2-family
+experiment models, and the benchmark's further archs.  `get_config(name)` /
+`get_smoke_config(name)`."""
 
 from __future__ import annotations
 
@@ -24,10 +25,15 @@ _REGISTRY = {
     "microllama-300m": "microllama_300m",
     "tinyllama-1.1b": "tinyllama_1_1b",
     "openllama-3b": "openllama_3b",
+    # further archs the benchmark runs
+    "deepseek-v2-lite-16b": "deepseek_v2_lite_16b",
 }
 
-ASSIGNED_ARCHS = tuple(list(_REGISTRY)[:10])
-PAPER_ARCHS = tuple(list(_REGISTRY)[10:])
+ASSIGNED_ARCHS = ("dbrx-132b", "phi3-mini-3.8b", "whisper-base",
+                  "deepseek-v2-236b", "recurrentgemma-9b", "internvl2-1b",
+                  "gemma2-27b", "nemotron-4-15b", "mamba2-370m",
+                  "llama3.2-1b")
+PAPER_ARCHS = ("microllama-300m", "tinyllama-1.1b", "openllama-3b")
 ALL_ARCHS = tuple(_REGISTRY)
 
 

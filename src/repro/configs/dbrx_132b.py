@@ -16,4 +16,4 @@ def smoke_config() -> ModelConfig:
     return CONFIG.replace(
         name="dbrx-smoke", num_layers=2, d_model=128, num_heads=4,
         num_kv_heads=2, head_dim=32, d_ff=128, vocab_size=512,
-        moe=MoEConfig(num_experts=4, top_k=2, d_expert=128, capacity_factor=2.0))
+        moe=MoEConfig(num_experts=4, top_k=2, d_expert=128))

@@ -50,6 +50,7 @@ from repro.core.norm_test import (
 from repro.optim.adamw import (
     AdamWConfig, init_adamw, init_adamw_flat, adamw_update,
     adamw_update_buffers, clip_scale_from_norm)
+from repro import spans
 from repro.distributed.flatbuf import FlatLayout
 from repro.distributed.params import param_pspecs, opt_pspecs
 from repro.distributed.sharding import (
@@ -175,9 +176,20 @@ def _flat_sharded_update(layout, params, gb, opt_state, opt_cfg, lr,
     return layout.unflatten(new_pb), new_opt, gnorm
 
 
-def _accumulate(loss_fn, params, batch, track_micro_sqnorm: bool):
+def _counters(model) -> tuple[str, ...]:
+    """The dispatch counters (`spans.COUNTERS`) the model's loss reports:
+    those of a model with expert layers, none otherwise."""
+    cfg = getattr(model, "cfg", None)
+    return spans.COUNTERS if getattr(cfg, "moe", None) is not None else ()
+
+
+def _accumulate(loss_fn, params, batch, track_micro_sqnorm: bool,
+                counters: tuple[str, ...] = ()):
     """lax.scan over the M stacked microbatches; returns (mean grads g,
-    mean loss, mean aux, Σ_m ‖ĝ^m‖² if tracked, effective microbatch count).
+    mean loss, extras, Σ_m ‖ĝ^m‖² if tracked, effective microbatch count,
+    valid-token weight).  `extras` holds the mean aux loss and the
+    `counters` the loss reports: expert pairs summed over the microbatches,
+    the load ratio their max.
 
     `loss_fn(params, microbatch) -> (loss, metrics)`; `params` is whatever
     the loss differentiates — the model pytree, or a tuple of flat-resident
@@ -193,7 +205,7 @@ def _accumulate(loss_fn, params, batch, track_micro_sqnorm: bool):
     loss and gradients."""
 
     def body(carry, mb):
-        acc_g, acc_loss, acc_aux, acc_sq, acc_w, acc_m = carry
+        acc_g, acc_loss, acc_ex, acc_sq, acc_w, acc_m = carry
         (loss, metrics), g = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
         w = jnp.sum(mb["labels"] >= 0).astype(jnp.float32)
         acc_g = jax.tree.map(lambda a, b: a + w * b.astype(jnp.float32), acc_g, g)
@@ -201,21 +213,29 @@ def _accumulate(loss_fn, params, batch, track_micro_sqnorm: bool):
             # fully-padded microbatches carry no gradient draw: skip them in
             # the Σ_m ‖ĝ^m‖² used by the accumulation-variance estimator
             acc_sq = acc_sq + jnp.where(w > 0, tree_sqnorm(g), 0.0)
-        return (acc_g, acc_loss + w * loss, acc_aux + w * metrics["aux"],
-                acc_sq, acc_w + w, acc_m + (w > 0)), None
+        acc_ex = dict(acc_ex, aux=acc_ex["aux"] + w * metrics["aux"])
+        if spans.EXPERT_ROWS in counters:
+            acc_ex[spans.EXPERT_ROWS] += metrics[spans.EXPERT_ROWS]
+        if spans.EXPERT_LOAD_MAX in counters:
+            acc_ex[spans.EXPERT_LOAD_MAX] = jnp.maximum(
+                acc_ex[spans.EXPERT_LOAD_MAX], metrics[spans.EXPERT_LOAD_MAX])
+        return (acc_g, acc_loss + w * loss, acc_ex, acc_sq, acc_w + w,
+                acc_m + (w > 0)), None
 
-    init = (_tree_zeros_f32(params), jnp.zeros((), jnp.float32),
-            jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32),
-            jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
-    (acc_g, acc_loss, acc_aux, acc_sq, acc_w, acc_m), _ = \
+    zero = jnp.zeros((), jnp.float32)
+    extras = {name: zero for name in ("aux",) + tuple(counters)}
+    init = (_tree_zeros_f32(params), zero, extras, zero, zero, zero)
+    (acc_g, acc_loss, acc_ex, acc_sq, acc_w, acc_m), _ = \
         jax.lax.scan(body, init, batch)
     denom = jnp.maximum(acc_w, 1.0)
     g = jax.tree.map(lambda x: x / denom, acc_g)
-    return g, acc_loss / denom, acc_aux / denom, acc_sq, acc_m, acc_w
+    acc_ex["aux"] = acc_ex["aux"] / denom
+    return g, acc_loss / denom, acc_ex, acc_sq, acc_m, acc_w
 
 
 def _accumulate_buffers(loss_fn, layout, pb, batch,
-                        track_micro_sqnorm: bool):
+                        track_micro_sqnorm: bool,
+                        counters: tuple[str, ...] = ()):
     """Flat-resident gradient accumulation (DESIGN §10): unflatten the
     param buffers ONCE per step, let the microbatch scan accumulate
     per-micro leaf cotangents with the EXACT arithmetic of the tree path
@@ -239,10 +259,10 @@ def _accumulate_buffers(loss_fn, layout, pb, batch,
 
     Returns `_accumulate`'s tuple with g as born-flat f32 buffers."""
     tree = layout.unflatten(list(pb))
-    g_tree, loss, aux, sq, m_eff, w = _accumulate(loss_fn, tree, batch,
-                                                  track_micro_sqnorm)
+    g_tree, loss, extras, sq, m_eff, w = _accumulate(
+        loss_fn, tree, batch, track_micro_sqnorm, counters)
     gb = layout.pack_cotangents(g_tree)
-    return gb, loss, aux, sq, m_eff, w
+    return gb, loss, extras, sq, m_eff, w
 
 
 # --------------------------------------------------------- FSDP-Norm ----
@@ -283,6 +303,7 @@ def make_fsdp_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
         base = with_sequence_parallel(base)
     manual = _manual_axes(mesh)
     rules = manual_data_rules(base, manual)
+    counters = _counters(model)
 
     if params_like is None:
         params_like = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -302,11 +323,11 @@ def make_fsdp_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
                 # ops on a 1-worker mesh)
                 pb_full = (tuple(params) if J == 1 else
                            tuple(gather_flat_buffers(params, daxes)))
-                g_j, loss, aux, _, _, w_j = _accumulate_buffers(
-                    model.loss, layout, pb_full, batch, False)
+                g_j, loss, extras, _, _, w_j = _accumulate_buffers(
+                    model.loss, layout, pb_full, batch, False, counters)
             else:
-                g_j, loss, aux, _, _, w_j = _accumulate(
-                    model.loss, params, batch, False)
+                g_j, loss, extras, _, _, w_j = _accumulate(
+                    model.loss, params, batch, False, counters)
             # valid-token-weighted mean over workers: equals plain pmean on
             # unpadded batches; exact under the engine's padding even when
             # the padded tail lands unevenly across workers (DESIGN §8)
@@ -332,7 +353,14 @@ def make_fsdp_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
             else:
                 var_l1, gsq = worker_variance_stats(g_j, g, daxes)
             loss = jax.lax.psum(loss * w_j, daxes) / w_sum
-            aux = jax.lax.psum(aux * w_j, daxes) / w_sum
+            aux = jax.lax.psum(extras["aux"] * w_j, daxes) / w_sum
+            counted = {}
+            if spans.EXPERT_ROWS in counters:
+                counted[spans.EXPERT_ROWS] = jax.lax.psum(
+                    extras[spans.EXPERT_ROWS], daxes)
+            if spans.EXPERT_LOAD_MAX in counters:
+                counted[spans.EXPERT_LOAD_MAX] = jax.lax.pmax(
+                    extras[spans.EXPERT_LOAD_MAX], daxes)
             if params_impl == "flat":
                 if stats_impl == "flat":
                     # the input params ARE the worker's param shard; the
@@ -363,7 +391,8 @@ def make_fsdp_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
                     params, g, opt_state, opt_cfg, lr)
         metrics = {"loss": loss, "aux": aux, "var_l1": var_l1,
                    "grad_sqnorm": gsq, "grad_norm": gnorm,
-                   "clip_scale": clip_scale_from_norm(gnorm, opt_cfg.grad_clip)}
+                   "clip_scale": clip_scale_from_norm(gnorm, opt_cfg.grad_clip),
+                   **counted}
         return new_params, new_opt, metrics
 
     p_tree_specs = param_pspecs(params_like, mesh, fsdp=False)
@@ -401,7 +430,7 @@ def make_fsdp_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
                        o_sm_specs,
                        {"loss": P(), "aux": P(), "var_l1": P(),
                         "grad_sqnorm": P(), "grad_norm": P(),
-                        "clip_scale": P()}),
+                        "clip_scale": P(), **{k: P() for k in counters}}),
             axis_names=set(manual), check_vma=False)
         if not jit:
             return sm
@@ -455,6 +484,7 @@ def make_accum_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
     daxes = data_axes(mesh)
     rules = _rules_for(mesh)
     J = num_workers(mesh)
+    counters = _counters(model)
 
     if params_like is None:
         params_like = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -472,8 +502,8 @@ def make_accum_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
                 # as committed jit inputs already carrying the P(daxes)
                 # in_shardings
                 pb = tuple(params)
-                g, loss, aux, sq_sum, m_eff, _ = _accumulate_buffers(
-                    model.loss, layout, pb, batch, True)
+                g, loss, extras, sq_sum, m_eff, _ = _accumulate_buffers(
+                    model.loss, layout, pb, batch, True, counters)
                 gb = shard_flat_buffers(list(g), daxes)
                 if stats_impl == "flat":
                     # born-flat buffers straight into the fused tail: the
@@ -500,8 +530,8 @@ def make_accum_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
                     new_params = tuple(shard_flat_buffers(
                         layout.flatten(new_tree), daxes))
             else:
-                g, loss, aux, sq_sum, m_eff, _ = _accumulate(
-                    model.loss, params, batch, True)
+                g, loss, extras, sq_sum, m_eff, _ = _accumulate(
+                    model.loss, params, batch, True, counters)
                 if stats_impl == "flat":
                     # pack g and params ONCE against the shared layout, keep
                     # the buffers on the data axes, and run the pack-free tail
@@ -521,7 +551,8 @@ def make_accum_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
                     var_l1, gsq = accum_variance_stats(sq_sum, g, m_eff, J)
                     new_params, new_opt, gnorm = adamw_update(
                         params, g, opt_state, opt_cfg, lr)
-        metrics = {"loss": loss, "aux": aux, "var_l1": var_l1,
+        # the aux loss and the dispatch counters (`_counters`), if any
+        metrics = {"loss": loss, **extras, "var_l1": var_l1,
                    "grad_sqnorm": gsq, "grad_norm": gnorm,
                    "clip_scale": clip_scale_from_norm(gnorm, opt_cfg.grad_clip)}
         return new_params, new_opt, metrics

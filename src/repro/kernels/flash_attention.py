@@ -2,9 +2,10 @@
 logit-softcap) with its backward — the TPU path of
 `repro.kernels.ops.attention_core`.
 
-Layout: q as (batch·heads, t, d), k/v as (batch·kv_heads, s, d); GQA folds
-into the index maps, so kv blocks are read once per q-head group without
-materialising repeats.
+Layout: q as (batch·heads, t, d), k as (batch·kv_heads, s, d) and v as
+(batch·kv_heads, s, d_v); the value width may differ from the query/key
+width (MLA: 192 and 128).  GQA folds into the index maps, so kv blocks are
+read once per q-head group without materialising repeats.
 
 Forward, grid (batch·heads, q_blocks, kv_blocks), kv innermost.  The
 online-softmax state (running max m, denominator l, accumulator acc) lives
@@ -230,10 +231,12 @@ def _operands(qf, kf, vf, c: _Cfg):
 
 
 def _forward(q, k, v, c: _Cfg):
-    """(o, lse) of (batch·heads, t, d) q against (batch·kv_heads, s, d)
-    k/v, all in the MXU dtype; o is in q's dtype as the caller passed it,
-    lse is (batch·heads, 1, t) f32."""
+    """(o, lse) of (batch·heads, t, d) q against (batch·kv_heads, s, d) k
+    and (batch·kv_heads, s, d_v) v, all in the MXU dtype; o is
+    (batch·heads, t, d_v) in q's dtype as the caller passed it, lse is
+    (batch·heads, 1, t) f32."""
     bh, t, d = q.shape
+    d_v = v.shape[-1]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, c=c),
         name="flash_attention_fwd",
@@ -241,18 +244,18 @@ def _forward(q, k, v, c: _Cfg):
         in_specs=[
             pl.BlockSpec((1, c.block_q, d), _q_map),
             pl.BlockSpec((1, c.block_kv, d), _kv_map(c)),
-            pl.BlockSpec((1, c.block_kv, d), _kv_map(c)),
+            pl.BlockSpec((1, c.block_kv, d_v), _kv_map(c)),
         ],
         out_specs=[
-            pl.BlockSpec((1, c.block_q, d), _q_map),
+            pl.BlockSpec((1, c.block_q, d_v), _q_map),
             pl.BlockSpec((1, 1, c.block_q), _row_map),
         ],
-        out_shape=[jax.ShapeDtypeStruct((bh, t, d), c.dtypes[0]),
+        out_shape=[jax.ShapeDtypeStruct((bh, t, d_v), c.dtypes[0]),
                    jax.ShapeDtypeStruct((bh, 1, t), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((c.block_q, 1), jnp.float32),
             pltpu.VMEM((c.block_q, 1), jnp.float32),
-            pltpu.VMEM((c.block_q, d), jnp.float32),
+            pltpu.VMEM((c.block_q, d_v), jnp.float32),
         ],
         compiler_params=_PARAMS,
         interpret=c.interpret,
@@ -325,6 +328,7 @@ def _backward(c: _Cfg, res, do):
     q, k, v, o, lse = res
     bh, t, d = q.shape
     bkvh, s, _ = k.shape
+    d_v = v.shape[-1]
     h, kvh, g = c.heads, c.kv_heads, c.group
     di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     di = di.reshape(bh, 1, t)
@@ -354,17 +358,17 @@ def _backward(c: _Cfg, res, do):
         in_specs=[
             pl.BlockSpec((1, c.block_q, d), q_dkv),
             pl.BlockSpec((1, c.block_kv, d), kv_dkv),
-            pl.BlockSpec((1, c.block_kv, d), kv_dkv),
-            pl.BlockSpec((1, c.block_q, d), q_dkv),
+            pl.BlockSpec((1, c.block_kv, d_v), kv_dkv),
+            pl.BlockSpec((1, c.block_q, d_v), q_dkv),
             pl.BlockSpec((1, 1, c.block_q), row_dkv),
             pl.BlockSpec((1, 1, c.block_q), row_dkv),
         ],
         out_specs=[pl.BlockSpec((1, c.block_kv, d), kv_dkv),
-                   pl.BlockSpec((1, c.block_kv, d), kv_dkv)],
+                   pl.BlockSpec((1, c.block_kv, d_v), kv_dkv)],
         out_shape=[jax.ShapeDtypeStruct((bkvh, s, d), jnp.float32),
-                   jax.ShapeDtypeStruct((bkvh, s, d), jnp.float32)],
+                   jax.ShapeDtypeStruct((bkvh, s, d_v), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((c.block_kv, d), jnp.float32),
-                        pltpu.VMEM((c.block_kv, d), jnp.float32)],
+                        pltpu.VMEM((c.block_kv, d_v), jnp.float32)],
         compiler_params=_PARAMS,
         interpret=c.interpret,
     )(q, k, v, dom, lse, di)
@@ -377,8 +381,8 @@ def _backward(c: _Cfg, res, do):
         in_specs=[
             pl.BlockSpec((1, c.block_q, d), _q_map),
             pl.BlockSpec((1, c.block_kv, d), _kv_map(c)),
-            pl.BlockSpec((1, c.block_kv, d), _kv_map(c)),
-            pl.BlockSpec((1, c.block_q, d), _q_map),
+            pl.BlockSpec((1, c.block_kv, d_v), _kv_map(c)),
+            pl.BlockSpec((1, c.block_q, d_v), _q_map),
             pl.BlockSpec((1, 1, c.block_q), _row_map),
             pl.BlockSpec((1, 1, c.block_q), _row_map),
         ],
@@ -414,9 +418,11 @@ def _attention_bwd(c: _Cfg, res, do):
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
-def _flat(q, k, v, causal, window, softcap, block_q, block_kv, interpret):
+def _flat(q, k, v, causal, window, softcap, block_q, block_kv, interpret,
+          scale=None):
     """q/k/v in the kernels' layout, padded keys, and their config."""
     b, t, h, d = q.shape
+    d_v = v.shape[-1]
     s, kvh = k.shape[1], k.shape[2]
     if h % kvh:
         raise ValueError(f"{h} query heads over {kvh} kv heads")
@@ -432,25 +438,27 @@ def _flat(q, k, v, causal, window, softcap, block_q, block_kv, interpret):
     mxu = (jnp.float32 if ip else
            jnp.bfloat16 if q.dtype == jnp.float32 else q.dtype)
     c = _Cfg(causal=causal, window=window, softcap=softcap,
-             scale=1.0 / math.sqrt(d), block_q=block_q, block_kv=block_kv,
+             scale=1.0 / math.sqrt(d) if scale is None else scale,
+             block_q=block_q, block_kv=block_kv,
              nq=t // block_q, nkv=pad_s // block_kv, seq_len=s, heads=h,
              kv_heads=kvh, mxu_dtype=mxu,
              dtypes=(q.dtype, k.dtype, v.dtype), interpret=ip)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * kvh, pad_s, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * kvh, pad_s, d)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * kvh, pad_s, d_v)
     return qf, kf, vf, c
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    softcap: float = 0.0, block_q: int = 1024,
-                    block_kv: int = 1024, interpret: bool | None = None):
-    """q: (b, t, h, d); k/v: (b, s, kv_heads, d) with h % kv_heads == 0.
-    Returns (b, t, h, d), differentiable in q, k and v.  Softmax scale is
-    1/sqrt(d)."""
+                    softcap: float = 0.0, scale: float | None = None,
+                    block_q: int = 1024, block_kv: int = 1024,
+                    interpret: bool | None = None):
+    """q: (b, t, h, d); k: (b, s, kv_heads, d); v: (b, s, kv_heads, d_v)
+    with h % kv_heads == 0.  Returns (b, t, h, d_v), differentiable in q,
+    k and v.  Softmax scale `scale`, 1/sqrt(d) by default."""
     b, t, h, d = q.shape
     qf, kf, vf, c = _flat(q, k, v, causal, window, softcap, block_q,
-                          block_kv, interpret)
+                          block_kv, interpret, scale)
     out = _attention(qf, kf, vf, c)
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, t, v.shape[-1]).transpose(0, 2, 1, 3)
 

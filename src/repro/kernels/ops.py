@@ -5,9 +5,10 @@ flag > `REPRO_PALLAS_INTERPRET` env override > backend auto-detect (compiled
 on TPU, interpreted elsewhere — this container is CPU-only; the kernels
 target TPU and are validated against ref.py in interpret mode).
 
-The `*_flat` entry points and `attention_core` at the bottom are the hot-path
-dispatch (DESIGN §9, §16): compiled Pallas on TPU, jnp otherwise
-(interpret-mode Pallas is a correctness tool, far too slow for a step).
+The `*_flat` entry points, `attention_core` and `grouped_matmul` at the
+bottom are the hot-path dispatch (DESIGN §9, §16): compiled Pallas on TPU,
+jnp otherwise (interpret-mode Pallas is a correctness tool, far too slow
+for a step).
 """
 
 from __future__ import annotations
@@ -27,6 +28,7 @@ from repro.kernels.fused_adamw import (
 from repro.kernels.fused_stats import fused_stats as _fused_stats
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm
 from repro.kernels.flash_attention import flash_attention as _flash_attention
+from repro.kernels.grouped_matmul import grouped_matmul as _grouped_matmul
 from repro.launch.mesh import data_axes
 
 
@@ -180,6 +182,8 @@ def flat_dispatch_info() -> dict:
         "flat_tail": "pallas-compiled" if _backend_is_tpu() else
                      "jnp-reference",
         "attention": "pallas-flash" if _backend_is_tpu() else "jnp",
+        "grouped_matmul": "pallas-megablox" if _backend_is_tpu() else
+                          "ragged-dot",
         "pallas_interpret_default": bool(_default_interpret()),
     }
 
@@ -200,7 +204,7 @@ def flash_block(t: int, s: int) -> int | None:
 
 def _on_local_heads(fn, q, k, v):
     """Run `fn(q, k, v)` on each device's batch and head shard of
-    (b, t, heads, d) operands.  GSPMD cannot partition a Mosaic call, so
+    (b, t, heads, width) operands.  GSPMD cannot partition a Mosaic call, so
     where the context mesh has Auto axes the call is wrapped in a
     `shard_map` manual over them: batch split over the data axes, heads
     over `model` where both head counts divide, other axes replicated."""
@@ -219,20 +223,34 @@ def _on_local_heads(fn, q, k, v):
                          axis_names=set(auto), check_vma=False)(q, k, v)
 
 
-def attention_core(q, k, v, *, causal: bool, window: int, softcap: float):
-    """Backend-dispatched softmax(q·kᵀ/√d)·v of q (b, t, h, d) over k/v
-    (b, s, kv_heads, d): the compiled Pallas flash kernels (forward and
-    backward) on TPU where `flash_block` gives a block, the jnp path of
+def attention_core(q, k, v, *, causal: bool, window: int, softcap: float,
+                   scale: float | None = None):
+    """Backend-dispatched softmax(q·kᵀ·scale)·v of q (b, t, h, d) over k
+    (b, s, kv_heads, d) and v (b, s, kv_heads, d_v), scale 1/√d by
+    default: the compiled Pallas flash kernels (forward and backward) on
+    TPU where `flash_block` gives a block, the jnp path of
     `repro.models.attention` elsewhere (the CPU path, and the only one for
     shapes the kernels do not take).  Traced inside the callers' jit."""
     block = flash_block(q.shape[1], k.shape[1])
     if _backend_is_tpu() and block is not None:
         return _on_local_heads(functools.partial(
             _flash_attention, causal=causal, window=window, softcap=softcap,
-            block_q=block, block_kv=block), q, k, v)
+            scale=scale, block_q=block, block_kv=block), q, k, v)
     # imported here: `repro.models.attention` imports this module
     from repro.models.attention import sdpa_self
-    return sdpa_self(q, k, v, causal=causal, window=window, softcap=softcap)
+    return sdpa_self(q, k, v, causal=causal, window=window, softcap=softcap,
+                     scale=scale)
+
+
+def grouped_matmul(x, w, group_sizes):
+    """Backend-dispatched grouped product of rows sorted by group, x (m, k),
+    with their group's matrix of w (groups, k, n), group i holding
+    `group_sizes[i]` rows: the megablox Pallas kernels on TPU, whose work
+    follows the group sizes, and `jax.lax.ragged_dot` elsewhere.  Rows past
+    the last group are undefined.  Traced inside the callers' jit."""
+    if _backend_is_tpu():
+        return _grouped_matmul(x, w, group_sizes)
+    return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))
@@ -242,10 +260,12 @@ def rmsnorm(x, scale, eps: float = 1e-6, interpret: bool | None = None):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "softcap", "block_q", "block_kv", "interpret"))
+    "causal", "window", "softcap", "scale", "block_q", "block_kv",
+    "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
-                    block_q=256, block_kv=256, interpret: bool | None = None):
+                    scale=None, block_q=256, block_kv=256,
+                    interpret: bool | None = None):
     ip = _default_interpret() if interpret is None else interpret
     return _flash_attention(q, k, v, causal=causal, window=window,
-                            softcap=softcap, block_q=block_q,
+                            softcap=softcap, scale=scale, block_q=block_q,
                             block_kv=block_kv, interpret=ip)

@@ -184,6 +184,21 @@ def init_train_state(model, key, mesh, wrap, p_specs, o_specs, *,
     return params, opt_state
 
 
+def _merge_counters(counted: dict, metrics: dict) -> dict:
+    """The step's dispatch counters (`spans.COUNTERS`, present for a model
+    with expert layers) read back and folded into `counted`: pairs add
+    over sub-steps, the load ratio is their max."""
+    out = dict(counted)
+    if spans.EXPERT_ROWS in metrics:
+        out[spans.EXPERT_ROWS] = (out.get(spans.EXPERT_ROWS, 0.0)
+                                  + float(metrics[spans.EXPERT_ROWS]))
+    if spans.EXPERT_LOAD_MAX in metrics:
+        out[spans.EXPERT_LOAD_MAX] = max(
+            out.get(spans.EXPERT_LOAD_MAX, 0.0),
+            float(metrics[spans.EXPERT_LOAD_MAX]))
+    return out
+
+
 def model_config(job: TrainJob):
     """The job's ModelConfig: smoke preset or published widths, with the
     job's activation-recomputation policy."""
@@ -484,7 +499,7 @@ def run_training(job: TrainJob) -> dict:
 
                 if use_af:
                     sub_plan, repeats = accum_free_plan(plan)
-                    sub_losses = []
+                    sub_losses, counted = [], {}
                     for m in range(repeats):
                         with spans.span(spans.BATCH, n):
                             sub_np = {k: v[m:m + 1]
@@ -510,6 +525,7 @@ def run_training(job: TrainJob) -> dict:
                         samples += sub_plan.global_batch
                         with spans.span(spans.READBACK, n):
                             sub_losses.append(float(metrics["loss"]))
+                            counted = _merge_counters(counted, metrics)
                             if m == repeats - 1:
                                 # the last sub-step's var_l1 sits on the
                                 # sub-batch scale (E[var_l1] ≈ trΣ·J/b):
@@ -543,6 +559,7 @@ def run_training(job: TrainJob) -> dict:
                         var_l1 = float(metrics["var_l1"])
                         gsq = float(metrics["grad_sqnorm"])
                         loss = float(metrics["loss"])
+                        counted = _merge_counters({}, metrics)
                     samples += plan.global_batch
                     exec_plan, opt_steps = plan, 1
                 step += 1
@@ -593,6 +610,8 @@ def run_training(job: TrainJob) -> dict:
                 history["time"].append(time.time() - t0)
                 history["accum_steps"].append(exec_plan.accum_steps)
                 history["opt_steps"].append(opt_steps)
+                for name, value in counted.items():
+                    history.setdefault(name, []).append(value)
                 history["pred_rung"].append(
                     ctrl.pred_rung if job.schedule == "adaptive" else 0)
                 history["pred_eta"].append(

@@ -54,8 +54,10 @@ def _project_qkv(params, x, positions, rope_theta, qk_norm: bool):
     return q, k, v
 
 
-def _sdpa(q, k, v, mask, softcap: float):
-    """q: (b,t,h,dk) k/v: (b,s,kv,dk); GQA via head grouping. mask: (b,t,s) or (t,s)."""
+def _sdpa(q, k, v, mask, softcap: float, scale: float | None = None):
+    """q: (b,t,h,dk) k: (b,s,kv,dk) v: (b,s,kv,dv); GQA via head grouping.
+    mask: (b,t,s) or (t,s).  Scores scaled by `scale`, 1/sqrt(dk) by
+    default."""
     b, t, h, dk = q.shape
     kv = k.shape[2]
     # GQA via *kv-head expansion* (kv tensors are small) instead of grouping
@@ -67,7 +69,7 @@ def _sdpa(q, k, v, mask, softcap: float):
         k = maybe_shard(k, "batch", None, "heads", None)
         v = maybe_shard(v, "batch", None, "heads", None)
     logits = jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32)
-    logits = logits / jnp.sqrt(dk)
+    logits = logits / jnp.sqrt(dk) if scale is None else logits * scale
     if softcap > 0:
         logits = softcap * jnp.tanh(logits / softcap)
     if mask is not None:
@@ -121,7 +123,8 @@ Q_CHUNK = 512
 CHUNK_THRESHOLD = 2048  # use q-chunked (flash-style) attention for t >= this
 
 
-def _sdpa_chunked(q, k, v, softcap, causal, window, q_chunk=Q_CHUNK):
+def _sdpa_chunked(q, k, v, softcap, causal, window, q_chunk=Q_CHUNK,
+                  scale=None):
     """Memory-bounded attention: lax.scan over query chunks so the logits
     buffer is O(q_chunk · s) instead of O(t · s): the CPU path, and the TPU
     path for shapes the Pallas flash kernels do not take."""
@@ -135,21 +138,22 @@ def _sdpa_chunked(q, k, v, softcap, causal, window, q_chunk=Q_CHUNK):
         qi, ci = xs
         mask = causal_mask(q_chunk, s, offset=ci * q_chunk, window=window) \
             if (causal or window > 0) else None
-        return carry, _sdpa(qi, k, v, mask, softcap)
+        return carry, _sdpa(qi, k, v, mask, softcap, scale)
 
     body = jax.checkpoint(body)
     _, out = jax.lax.scan(body, None, (qc, jnp.arange(nc) * 1))
-    return jnp.moveaxis(out, 0, 1).reshape(b, t, h, dk)
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, h, v.shape[-1])
 
 
-def sdpa_self(q, k, v, *, causal, window, softcap):
-    """The jnp attention core of `attend_full`: q-chunked from
-    CHUNK_THRESHOLD positions on, else one score matrix."""
+def sdpa_self(q, k, v, *, causal, window, softcap, scale=None):
+    """The jnp attention core of `attend_full` and `mla_full`: q-chunked
+    from CHUNK_THRESHOLD positions on, else one score matrix.  v's width
+    may differ from q's and k's; `scale` is 1/sqrt(q's width) by default."""
     t = q.shape[1]
     if t >= CHUNK_THRESHOLD and t % Q_CHUNK == 0:
-        return _sdpa_chunked(q, k, v, softcap, causal, window)
+        return _sdpa_chunked(q, k, v, softcap, causal, window, scale=scale)
     mask = causal_mask(t, t, 0, window) if causal else None
-    return _sdpa(q, k, v, mask, softcap)
+    return _sdpa(q, k, v, mask, softcap, scale)
 
 
 def attend_full(params, x, positions, *, rope_theta, softcap=0.0, window=0,

@@ -3,16 +3,17 @@ pre/post norms, dense-MLP or MoE feed-forward, residuals.
 
 Every block exposes:
   * block_full(params, x, positions, cfg, kind, moe_layer, collect_cache)
-        -> (x, aux_loss, cache | None)        # training / prefill
+        -> (x, MoEStats, cache | None)        # training / prefill
   * block_decode(params, x, cache, pos, cfg, kind, moe_layer, ring)
-        -> (x, aux_loss, new_cache)           # single-token serving
+        -> (x, MoEStats, new_cache)           # single-token serving
+(`MoEStats` of an expert layer — balance loss and dispatch counters — or
+zeros.)
   * init_block / init_block_cache
 """
 
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from repro import spans
@@ -22,7 +23,7 @@ from repro.models import rglru as rglru_lib
 from repro.models import ssd as ssd_lib
 from repro.models.config import ModelConfig, ATTN, LOCAL_ATTN, MLA_ATTN, RGLRU, SSD
 from repro.models.mlp import init_mlp, apply_mlp
-from repro.models.moe import init_moe, moe_apply
+from repro.models.moe import MoEStats, init_moe, moe_apply
 from repro.models.norms import init_norm, apply_norm
 from repro.models.common import split_keys
 from repro.distributed.sharding import maybe_shard
@@ -77,9 +78,12 @@ def _mixer_full(params, x, positions, cfg: ModelConfig, kind: str,
                 params["attn"], x, positions, rope_theta, cfg.qk_norm)
             cache = {"k": k, "v": v}
     elif kind == MLA_ATTN:
-        out = mla_lib.mla_full(params["attn"], x, positions, cfg.mla)
+        with jax.named_scope(spans.ATTENTION):
+            out = mla_lib.mla_full(params["attn"], x, positions, cfg.mla,
+                                   cfg.rope_theta, causal=causal)
         if collect_cache:
-            c_kv, k_rope = mla_lib._latents(params["attn"], x, positions, cfg.mla)
+            c_kv, k_rope = mla_lib._latents(params["attn"], x, positions,
+                                            cfg.mla, cfg.rope_theta)
             cache = {"c_kv": c_kv, "k_rope": k_rope}
     elif kind == RGLRU:
         out = rglru_lib.rglru_block(params["rec"], x, cfg.rglru)
@@ -93,7 +97,7 @@ def _mixer_full(params, x, positions, cfg: ModelConfig, kind: str,
 
 def block_full(params, x, positions, cfg: ModelConfig, kind: str,
                moe_layer: bool, collect_cache: bool = False, causal: bool = True):
-    aux = jnp.zeros((), jnp.float32)
+    stats = MoEStats.zeros()
     h = apply_norm(params["pre_norm"], x, cfg.norm_kind)
     mixed, cache = _mixer_full(params, h, positions, cfg, kind, collect_cache, causal)
     if cfg.post_attn_norm:
@@ -107,7 +111,7 @@ def block_full(params, x, positions, cfg: ModelConfig, kind: str,
     if _has_mlp(cfg, kind):
         h = apply_norm(params["mlp_norm"], x, cfg.norm_kind)
         if moe_layer:
-            h, aux = moe_apply(params["mlp"], h, cfg.moe)
+            h, stats = moe_apply(params["mlp"], h, cfg.moe)
         else:
             h = apply_mlp(params["mlp"], h, cfg.mlp_kind)
         if cfg.post_attn_norm:
@@ -115,7 +119,7 @@ def block_full(params, x, positions, cfg: ModelConfig, kind: str,
         h = checkpoint_name(h, "tp_out")
         h = maybe_shard(h, "batch", "act_seq", "embed")
         x = x + h
-    return x, aux, cache
+    return x, stats, cache
 
 
 def init_block_cache(cfg: ModelConfig, kind: str, batch: int, cache_len: int, dtype):
@@ -133,7 +137,7 @@ def init_block_cache(cfg: ModelConfig, kind: str, batch: int, cache_len: int, dt
 
 def block_decode(params, x, cache, pos, cfg: ModelConfig, kind: str,
                  moe_layer: bool, ring: bool = False):
-    aux = jnp.zeros((), jnp.float32)
+    stats = MoEStats.zeros()
     h = apply_norm(params["pre_norm"], x, cfg.norm_kind)
     if kind in (ATTN, LOCAL_ATTN):
         # local-attn caches are rings by construction (length == window)
@@ -143,7 +147,9 @@ def block_decode(params, x, cache, pos, cfg: ModelConfig, kind: str,
             params["attn"], h, cache, pos, rope_theta=rope_theta,
             softcap=cfg.attn_logit_softcap, ring=is_ring, qk_norm=cfg.qk_norm)
     elif kind == MLA_ATTN:
-        mixed, new_cache = mla_lib.mla_decode(params["attn"], h, cache, pos, cfg.mla, ring=ring)
+        mixed, new_cache = mla_lib.mla_decode(params["attn"], h, cache, pos,
+                                              cfg.mla, cfg.rope_theta,
+                                              ring=ring)
     elif kind == RGLRU:
         mixed, new_cache = rglru_lib.rglru_decode(params["rec"], h, cache, cfg.rglru)
     elif kind == SSD:
@@ -156,11 +162,10 @@ def block_decode(params, x, cache, pos, cfg: ModelConfig, kind: str,
     if _has_mlp(cfg, kind):
         h = apply_norm(params["mlp_norm"], x, cfg.norm_kind)
         if moe_layer:
-            h, aux = moe_apply(params["mlp"], h, cfg.moe,
-                               capacity_factor=max(2.0, cfg.moe.capacity_factor))
+            h, stats = moe_apply(params["mlp"], h, cfg.moe)
         else:
             h = apply_mlp(params["mlp"], h, cfg.mlp_kind)
         if cfg.post_attn_norm:
             h = apply_norm(params["post_mlp_norm"], h, cfg.norm_kind)
         x = x + h
-    return x, aux, new_cache
+    return x, stats, new_cache

@@ -27,16 +27,27 @@ LAYER_KINDS = (ATTN, LOCAL_ATTN, MLA_ATTN, RGLRU, SSD)
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int              # experts the router routes over
     top_k: int
     d_expert: int                 # ffn width of each routed expert
     num_shared_experts: int = 0
     shared_d_expert: int = 0
+    # α of the sequence-level balance loss (DeepSeek-V2's seq_aux)
     router_aux_coef: float = 0.01
-    capacity_factor: float = 1.25
     # Which layers are MoE: every layer by default; first_dense skips layer 0
     # (DeepSeek-V2 keeps layer 0 dense).
     first_dense: int = 0
+    # routed experts this chip holds, experts [0, held_experts) under expert
+    # parallelism; 0 => all of them
+    held_experts: int = 0
+    # top-k gates renormalised to sum to 1 (DBRX) or kept as the router's
+    # probabilities (DeepSeek-V2: norm_topk_prob false)
+    normalize_gates: bool = True
+    routed_scaling_factor: float = 1.0
+
+    @property
+    def held(self) -> int:
+        return self.held_experts or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -46,6 +57,17 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # YaRN RoPE scaling (DeepSeek-V2's rope_scaling); factor 1 => plain RoPE
+    rope_factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -166,11 +188,17 @@ class ModelConfig:
         return jnp.dtype(self.param_dtype)
 
     def replace(self, **kw) -> "ModelConfig":
+        """`dataclasses.replace`; a dict given for a nested config field
+        (`moe`, `mla`, ...) is merged into the config that is there."""
+        for name, value in kw.items():
+            if isinstance(value, dict):
+                kw[name] = dataclasses.replace(getattr(self, name), **value)
         return dataclasses.replace(self, **kw)
 
     # ---- parameter counting (for roofline MODEL_FLOPS = 6*N*D) ----
     def param_count(self, active_only: bool = False) -> int:
-        """Approximate parameter count; active_only counts MoE top-k experts."""
+        """Approximate parameter count; MoE layers count the experts this
+        chip holds (`MoEConfig.held`), or the top-k with active_only."""
         d = self.d_model
         layers = [(k, False) for k in self.prefix_pattern]
         layers += [(k, self.moe is not None) for k in self.block_pattern] * self.num_repeats
@@ -198,10 +226,10 @@ class ModelConfig:
             m = self.mla
             qd = self.num_heads * (m.qk_nope_head_dim + m.qk_rope_head_dim)
             if m.q_lora_rank:
-                p += d * m.q_lora_rank + m.q_lora_rank * qd
+                p += d * m.q_lora_rank + m.q_lora_rank * qd + m.q_lora_rank
             else:
                 p += d * qd
-            p += d * (m.kv_lora_rank + m.qk_rope_head_dim)
+            p += d * (m.kv_lora_rank + m.qk_rope_head_dim) + m.kv_lora_rank
             p += m.kv_lora_rank * self.num_heads * (m.qk_nope_head_dim + m.v_head_dim)
             p += self.num_heads * m.v_head_dim * d
         elif kind == RGLRU:
@@ -225,7 +253,7 @@ class ModelConfig:
         d = self.d_model
         if self.moe is not None and moe_layer:
             m = self.moe
-            n_routed = m.top_k if active_only else m.num_experts
+            n_routed = m.top_k if active_only else m.held
             per_expert = 3 * d * m.d_expert if self.mlp_kind in ("swiglu", "geglu") else 2 * d * m.d_expert
             n = n_routed * per_expert + d * m.num_experts  # router
             if m.num_shared_experts:

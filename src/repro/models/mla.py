@@ -1,20 +1,29 @@
-"""DeepSeek-V2 Multi-head Latent Attention (MLA).
+"""DeepSeek-V2 Multi-head Latent Attention (MLA), with YaRN RoPE scaling.
 
 Training/prefill uses the expanded form; decode uses the *absorbed* form that
 attends directly in the compressed latent space (the whole point of MLA: the
 KV cache stores only `c_kv` (rank 512) plus the shared RoPE key, instead of
 full per-head K/V).
+
+RoPE rotates by halves.  DeepSeek's published code first de-interleaves
+the RoPE columns of the query and the shared key; that is a fixed
+permutation of the RoPE columns of `w_q` and `w_krope`, a layout of the
+weights, not another function.
 """
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
+from repro import spans
+from repro.kernels import ops
 from repro.models.common import normal_init, split_keys
 from repro.models.norms import init_norm, apply_norm
-from repro.models.embeddings import apply_rope
-from repro.models.attention import causal_mask, NEG_INF
+from repro.models.embeddings import apply_rope, yarn_frequencies, yarn_mscale
+from repro.models.attention import NEG_INF
 from repro.models.config import MLAConfig
 from repro.distributed.sharding import maybe_shard
 
@@ -38,7 +47,29 @@ def init_mla(key, d_model: int, num_heads: int, m: MLAConfig, dtype):
     return p
 
 
-def _queries(params, x, positions, m: MLAConfig):
+def _rope(x, positions, m: MLAConfig, theta: float):
+    """RoPE over the last axis of x (..., seq, heads, rope width), with
+    YaRN's frequencies and cos/sin factor where the config scales."""
+    if m.rope_factor <= 1:
+        return apply_rope(x, positions, theta)
+    freqs = yarn_frequencies(x.shape[-1], theta, m.rope_factor,
+                             m.original_max_position, m.beta_fast,
+                             m.beta_slow)
+    scale = (yarn_mscale(m.rope_factor, m.mscale)
+             / yarn_mscale(m.rope_factor, m.mscale_all_dim))
+    return apply_rope(x, positions, theta, freqs=freqs, scale=scale)
+
+
+def softmax_scale(m: MLAConfig) -> float:
+    """1/sqrt(query/key width), times YaRN's mscale² where the config
+    scales (DeepSeek-V2: (0.1·mscale_all_dim·ln factor + 1)²)."""
+    scale = 1.0 / math.sqrt(m.qk_head_dim)
+    if m.rope_factor > 1 and m.mscale_all_dim:
+        scale *= yarn_mscale(m.rope_factor, m.mscale_all_dim) ** 2
+    return scale
+
+
+def _queries(params, x, positions, m: MLAConfig, theta: float):
     if "w_dq" in params:
         cq = jnp.einsum("btd,dr->btr", x, params["w_dq"].astype(x.dtype))
         cq = apply_norm(params["q_norm"], cq, "rmsnorm")
@@ -47,58 +78,36 @@ def _queries(params, x, positions, m: MLAConfig):
         q = jnp.einsum("btd,dhk->bthk", x, params["w_q"].astype(x.dtype))
     q = maybe_shard(q, "batch", "seq", "heads", None)
     q_nope = q[..., : m.qk_nope_head_dim]
-    q_rope = apply_rope(q[..., m.qk_nope_head_dim:], positions, 10000.0)
+    q_rope = _rope(q[..., m.qk_nope_head_dim:], positions, m, theta)
     return q_nope, q_rope
 
 
-def _latents(params, x, positions, m: MLAConfig):
+def _latents(params, x, positions, m: MLAConfig, theta: float):
     c_kv = jnp.einsum("btd,dr->btr", x, params["w_dkv"].astype(x.dtype))
     c_kv = apply_norm(params["kv_norm"], c_kv, "rmsnorm")
     k_rope = jnp.einsum("btd,dr->btr", x, params["w_krope"].astype(x.dtype))
     # shared rope key: single head
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, 10000.0)[:, :, 0, :]
+    k_rope = _rope(k_rope[:, :, None, :], positions, m, theta)[:, :, 0, :]
     return c_kv, k_rope
 
 
-def _mla_attend(q_nope, q_rope, k_nope, k_rope, v, m: MLAConfig,
-                causal: bool, offset=0):
-    """One (possibly chunked) MLA attention: q over full kv."""
-    t, s = q_nope.shape[1], k_nope.shape[1]
-    scale = 1.0 / jnp.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
-    logits = jnp.einsum("bthn,bshn->bhts", q_nope, k_nope)
-    logits += jnp.einsum("bthr,bsr->bhts", q_rope, k_rope)
-    logits = logits.astype(jnp.float32) * scale
-    if causal:
-        mask = causal_mask(t, s, offset=offset)
-        logits = jnp.where(mask[None, None], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhts,bshv->bthv", probs, v)
-
-
-def mla_full(params, x, positions, m: MLAConfig, causal: bool = True,
-             q_chunk: int = 512):
-    """Expanded-form MLA over a full sequence (training / prefill); queries
-    are chunk-scanned for long sequences (flash-style memory bound)."""
-    b, t, _ = x.shape
-    q_nope, q_rope = _queries(params, x, positions, m)
-    c_kv, k_rope = _latents(params, x, positions, m)
+def mla_full(params, x, positions, m: MLAConfig, theta: float,
+             causal: bool = True):
+    """Expanded-form MLA over a full sequence (training / prefill): keys
+    are [k_nope ‖ the shared RoPE key, broadcast to every head], and the
+    core (`ops.attention_core`: the flash kernels on TPU) runs at query/key
+    width qk_nope + qk_rope and value width v_head_dim."""
+    q_nope, q_rope = _queries(params, x, positions, m, theta)
+    c_kv, k_rope = _latents(params, x, positions, m, theta)
     k_nope = jnp.einsum("btr,rhn->bthn", c_kv, params["w_uk"].astype(x.dtype))
     v = jnp.einsum("btr,rhv->bthv", c_kv, params["w_uv"].astype(x.dtype))
-    if t >= 2048 and t % q_chunk == 0:
-        nc = t // q_chunk
-        qn = jnp.moveaxis(q_nope.reshape(b, nc, q_chunk, *q_nope.shape[2:]), 1, 0)
-        qr = jnp.moveaxis(q_rope.reshape(b, nc, q_chunk, *q_rope.shape[2:]), 1, 0)
-
-        def body(carry, xs):
-            qni, qri, ci = xs
-            return carry, _mla_attend(qni, qri, k_nope, k_rope, v, m, causal,
-                                      offset=ci * q_chunk)
-
-        body = jax.checkpoint(body)
-        _, out = jax.lax.scan(body, None, (qn, qr, jnp.arange(nc)))
-        out = jnp.moveaxis(out, 0, 1).reshape(b, t, *out.shape[3:])
-    else:
-        out = _mla_attend(q_nope, q_rope, k_nope, k_rope, v, m, causal)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k_rope = jnp.broadcast_to(k_rope[:, :, None, :],
+                              (*k_nope.shape[:3], m.qk_rope_head_dim))
+    k = jnp.concatenate([k_nope, k_rope], axis=-1)
+    with jax.named_scope(spans.ATTENTION_CORE):
+        out = ops.attention_core(q, k, v, causal=causal, window=0,
+                                 softcap=0.0, scale=softmax_scale(m))
     out = jnp.einsum("bthv,hvd->btd", out, params["w_o"].astype(x.dtype))
     return maybe_shard(out, "batch", "seq", "embed")
 
@@ -110,7 +119,8 @@ def init_mla_cache(batch: int, cache_len: int, m: MLAConfig, dtype):
     }
 
 
-def mla_decode(params, x, cache, pos, m: MLAConfig, ring: bool = False):
+def mla_decode(params, x, cache, pos, m: MLAConfig, theta: float,
+               ring: bool = False):
     """Absorbed-form single-token decode against the latent cache.  `pos` is
     a scalar int32, or a (b,) int32 vector of per-row positions (continuous-
     batching serving — each row writes and masks its own timeline)."""
@@ -118,8 +128,8 @@ def mla_decode(params, x, cache, pos, m: MLAConfig, ring: bool = False):
     pos = jnp.asarray(pos, jnp.int32)
     per_row = pos.ndim == 1
     positions = pos[:, None] if per_row else jnp.full((b, 1), pos, jnp.int32)
-    q_nope, q_rope = _queries(params, x, positions, m)          # (b,1,h,*)
-    c_new, kr_new = _latents(params, x, positions, m)           # (b,1,r)
+    q_nope, q_rope = _queries(params, x, positions, m, theta)   # (b,1,h,*)
+    c_new, kr_new = _latents(params, x, positions, m, theta)    # (b,1,r)
     cache_len = cache["c_kv"].shape[1]
     slot = pos % cache_len if ring else pos
     if per_row:
@@ -135,10 +145,9 @@ def mla_decode(params, x, cache, pos, m: MLAConfig, ring: bool = False):
             cache["k_rope"], kr_new.astype(cache["k_rope"].dtype), (0, slot, 0))
     # absorb W_uk into the query: attend in latent space
     q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, params["w_uk"].astype(x.dtype))
-    scale = 1.0 / jnp.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
     logits = jnp.einsum("bthr,bsr->bhts", q_lat, c_kv.astype(x.dtype))
     logits += jnp.einsum("bthr,bsr->bhts", q_rope, k_rope.astype(x.dtype))
-    logits = logits.astype(jnp.float32) * scale
+    logits = logits.astype(jnp.float32) * softmax_scale(m)
     kpos = jnp.arange(cache_len)
     ppos = pos[:, None] if per_row else pos
     if ring:

@@ -1,40 +1,72 @@
-"""Mixture-of-Experts layer: top-k routing, sort-based capacity dispatch,
-shared experts (DeepSeek-V2), switch-style load-balance aux loss.
+"""Mixture-of-Experts layer: a router over all `num_experts`, top-k gates,
+dropless dispatch to the experts this chip holds, grouped matrix products,
+shared experts, and DeepSeek-V2's sequence-level balance loss.
 
-Dispatch is the TPU-friendly sort/capacity scheme: token-expert pairs are
-sorted by expert id, truncated to a static per-expert capacity, batched into
-an (E, C, d) tensor and processed with a single (E,d,f) einsum — MXU-dense,
-expert dim sharded over the `model` mesh axis (expert parallelism).  Tokens
-over capacity are dropped (standard GShard/Switch behaviour); capacity_factor
-controls the drop rate.
+Expert parallelism: the layer holds routed experts [0, `MoEConfig.held`)
+(the chip's share; all of them by default) and computes their part of each
+token's result.  The router still scores every expert and picks each
+token's top k of all of them; pairs that go to experts held elsewhere are
+computed by nobody here, and what those experts would add is left out (on
+one chip the layer runs without its exchange).  Where the mesh has a
+`model` axis, the held stacks stay split over it (`moe_apply`).
 
-Sharding-critical structure (measured in EXPERIMENTS.md §Perf-2):
-  * dispatch groups are batch rows (GShard "groups") so the argsort is
-    shard-local under any batch sharding;
-  * both dispatch and combine are *slot-major* — the expert-sharded (E, C, d)
-    tensor is produced by a gather (local fwd, cheap bwd) and consumed by a
-    scatter-add whose only collective is an (n, d) all-reduce.  Pair-major
-    formulations make GSPMD replicate (n·k, d) buffers (24 GB/layer on
-    deepseek-v2).
+Dispatch is dropless.  The dispatch group is every token of the call (a
+device's local tokens of one microbatch).  Its token-expert pairs that go
+to held experts are sorted by expert, one group per held expert, and the
+three products of the SwiGLU experts run as grouped matmuls
+(`kernels.ops.grouped_matmul`) over the sorted rows, whose work follows
+the actual group sizes.  The row buffer is sized for the worst case
+dropless allows, n·min(k, held) pairs; rows past the held pairs are masked
+on the way in and out.  The combine adds each pair's gate-weighted output
+back to its token.  Shared experts are dense and counted once.
+
+The layer returns, besides the output, the balance loss and two counters
+of the dispatch (`MoEStats`): the pairs computed on held experts and the
+largest held expert's pairs over the mean.
 """
 
 from __future__ import annotations
 
+import math
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro import spans
+from repro.distributed.sharding import DEFAULT_RULES, current_rules
+from repro.kernels import ops
+from repro.launch.mesh import data_axes
 from repro.models.common import normal_init, split_keys
 from repro.models.config import MoEConfig
-from repro.distributed.sharding import maybe_shard
+
+
+class MoEStats(NamedTuple):
+    """What one expert layer reports besides its output (f32 scalars)."""
+    aux: jax.Array          # balance loss, times its coefficient
+    rows: jax.Array         # token-expert pairs computed on held experts
+    load_max: jax.Array     # largest held expert's pairs over the mean
+
+    @staticmethod
+    def zeros() -> "MoEStats":
+        z = jnp.zeros((), jnp.float32)
+        return MoEStats(z, z, z)
+
+    def merge(self, other: "MoEStats") -> "MoEStats":
+        """Over layers: losses and pairs add, the load ratio is the max."""
+        return MoEStats(self.aux + other.aux, self.rows + other.rows,
+                        jnp.maximum(self.load_max, other.load_max))
 
 
 def init_moe(key, d_model: int, m: MoEConfig, mlp_kind: str, dtype):
     k_r, k_g, k_u, k_d, k_s = split_keys(key, 5)
+    h = m.held
     p = {
         "router": normal_init(k_r, (d_model, m.num_experts), dtype),
-        "w_gate": normal_init(k_g, (m.num_experts, d_model, m.d_expert), dtype),
-        "w_up": normal_init(k_u, (m.num_experts, d_model, m.d_expert), dtype),
-        "w_down": normal_init(k_d, (m.num_experts, m.d_expert, d_model), dtype),
+        "w_gate": normal_init(k_g, (h, d_model, m.d_expert), dtype),
+        "w_up": normal_init(k_u, (h, d_model, m.d_expert), dtype),
+        "w_down": normal_init(k_d, (h, m.d_expert, d_model), dtype),
     }
     if m.num_shared_experts:
         width = m.num_shared_experts * m.shared_d_expert
@@ -47,100 +79,152 @@ def init_moe(key, d_model: int, m: MoEConfig, mlp_kind: str, dtype):
     return p
 
 
-def _capacity(num_tokens: int, m: MoEConfig, capacity_factor: float) -> int:
-    c = int(capacity_factor * num_tokens * m.top_k / m.num_experts)
-    return max(min(c, num_tokens), 1)
+def route(params, x, m: MoEConfig):
+    """Router probabilities over all experts (f32, at `highest` precision,
+    as DeepSeek's gate computes in fp32) and each token's top-k experts
+    with their gates.  x: (b, t, d) -> probs (b, t, E), experts and gates
+    (b, t, k)."""
+    logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32),
+                        params["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, m.top_k)
+    if m.normalize_gates:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return probs, experts, gates * m.routed_scaling_factor
 
 
-def moe_apply(params, x, m: MoEConfig, *, capacity_factor: float | None = None,
-              normalize_gates: bool = True):
-    """x: (b, t, d) -> (out, aux_loss).
+def balance_loss(probs, experts, m: MoEConfig):
+    """DeepSeek-V2's sequence-level balance loss, times α: per sequence,
+    (E/k)·Σ_e (count_e/T)·mean_t p_{t,e}, averaged over sequences."""
+    b, t, e = probs.shape
+    counts = jnp.sum(jax.nn.one_hot(experts, e, dtype=jnp.float32),
+                     axis=(1, 2))                                  # (b, E)
+    per_seq = (e / m.top_k) * jnp.sum(counts / t * jnp.mean(probs, axis=1),
+                                      axis=-1)
+    return m.router_aux_coef * jnp.mean(per_seq)
 
-    Dispatch groups are batch rows (GShard "groups"): the sort and the
-    capacity budget are per-row, so with the batch sharded over the data axes
-    the entire dispatch is shard-local — no global argsort collectives
-    (§Perf-2.2).  Capacity C = factor·t·top_k/E per row."""
+
+def _experts(params, x, experts, gates, m: MoEConfig, first):
+    """This device's held experts' gate-weighted part of each token's
+    result, its pairs on them and its largest expert's pairs.  The held
+    block is experts [first, first + held), held being the stacks' leading
+    size.  x: (n, d); experts, gates: (n, k)."""
+    n, d = x.shape
+    k, held = m.top_k, params["w_up"].shape[0]
+    rel = experts.reshape(-1) - first
+    # pairs of held experts first, sorted by expert; the rest (key `held`)
+    # after them, and one_hot gives them a zero row
+    key = jnp.where((rel >= 0) & (rel < held), rel, held)
+    order = jnp.argsort(key, stable=True)[:n * min(k, held)]
+    group_sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
+    total = jnp.sum(group_sizes)
+    valid = (jnp.arange(order.shape[0]) < total)[:, None]
+    token = order // k
+    dt = x.dtype
+    # rows past the held pairs are undefined in every product's output:
+    # select, never multiply, on the way in and out, so neither they nor
+    # their cotangents reach a token or a gate
+    rows = jnp.where(valid, x[token], 0)
+    with jax.named_scope(spans.EXPERT_MATMUL):
+        gate = ops.grouped_matmul(rows, params["w_gate"].astype(dt),
+                                  group_sizes)
+        up = ops.grouped_matmul(rows, params["w_up"].astype(dt), group_sizes)
+        out = ops.grouped_matmul(jax.nn.silu(gate) * up,
+                                 params["w_down"].astype(dt), group_sizes)
+    pair_gate = gates.reshape(-1)[order].astype(dt)
+    out = jnp.where(valid, out, 0) * pair_gate[:, None]
+    y = jnp.zeros((n, d), dt).at[token].add(out)
+    return y, total.astype(jnp.float32), \
+        jnp.max(group_sizes).astype(jnp.float32)
+
+
+def _shared(params, x):
+    sh = params["shared"]
+    dt = x.dtype
+    hs = jax.nn.silu(jnp.einsum("btd,df->btf", x, sh["w_gate"].astype(dt)))
+    hs = hs * jnp.einsum("btd,df->btf", x, sh["w_up"].astype(dt))
+    return jnp.einsum("btf,fd->btd", hs, sh["w_down"].astype(dt))
+
+
+def _layer(params, x, m: MoEConfig, daxes: tuple, eaxis):
     b, t, d = x.shape
+    probs, experts, gates = route(params, x, m)
+    aux = balance_loss(probs, experts, m)
+    held = params["w_up"].shape[0]
+    first = jax.lax.axis_index(eaxis) * held if eaxis else 0
+    y, rows, most = _experts(params, x.reshape(b * t, d),
+                             experts.reshape(b * t, -1),
+                             gates.reshape(b * t, -1), m, first)
+    y = y.reshape(b, t, d)
+    sh = params.get("shared")
+    split = (sh is not None and
+             sh["w_up"].shape[1] < m.num_shared_experts * m.shared_d_expert)
+    if split:
+        y = y + _shared(params, x)
+    if eaxis:
+        # each device holds a block of the experts (and of the shared
+        # width where it divides): their parts of every token's result add
+        y = jax.lax.psum(y, eaxis)
+        rows = jax.lax.psum(rows, eaxis)
+        most = jax.lax.pmax(most, eaxis)
+    if sh is not None and not split:
+        y = y + _shared(params, x)
+    load_max = jnp.where(rows > 0, most * m.held / jnp.maximum(rows, 1.0),
+                         0.0)
+    if daxes:
+        aux = jax.lax.pmean(aux, daxes)
+        rows = jax.lax.psum(rows, daxes)
+        load_max = jax.lax.pmax(load_max, daxes)
+    return y, MoEStats(aux, rows, load_max)
 
-    def row(xt):
-        return _moe_row(params, xt, m, capacity_factor, normalize_gates)
 
-    y, aux = jax.vmap(row)(x)
-    return maybe_shard(y, "batch", "seq", "embed"), jnp.mean(aux)
+def _expert_axis(mesh, auto, params):
+    """The mesh axis the expert stacks are split over (the `experts`
+    sharding rule, as `distributed.params` lays the weights out), or None
+    where it is not an Auto axis of more than one device or does not
+    divide the held experts."""
+    axes = (current_rules() or DEFAULT_RULES).rules.get("experts")
+    axes = (axes,) if isinstance(axes, str) else tuple(axes or ())
+    if len(axes) != 1 or axes[0] not in auto:
+        return None
+    size = mesh.shape[axes[0]]
+    if size < 2 or params["w_up"].shape[0] % size:
+        return None
+    return axes[0]
 
 
-def _moe_row(params, xt, m: MoEConfig, capacity_factor, normalize_gates):
-    """One dispatch group. xt: (n, d) -> ((n, d), aux)."""
-    n, d = xt.shape
-    dt = xt.dtype
-    router_logits = jnp.einsum("nd,de->ne", xt, params["router"].astype(dt))
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    gates, expert_idx = jax.lax.top_k(probs, m.top_k)               # (n, k)
-    if normalize_gates:
-        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-9)
+@spans.scoped(spans.MOE)
+def moe_apply(params, x, m: MoEConfig):
+    """x: (b, t, d) -> (out, MoEStats); training, prefill and decode alike.
 
-    # switch-style load balance loss over all-k assignments
-    one_hot_k = jax.nn.one_hot(expert_idx, m.num_experts, dtype=jnp.float32)  # (n,k,E)
-    frac_tokens = jnp.mean(jnp.sum(one_hot_k, axis=1), axis=0)      # (E,)
-    frac_probs = jnp.mean(probs, axis=0)                            # (E,)
-    aux = m.num_experts * jnp.sum(frac_tokens * frac_probs) * m.router_aux_coef
-
-    # ---- sort-based capacity dispatch ----
-    cap = _capacity(n, m, capacity_factor if capacity_factor is not None
-                    else m.capacity_factor)
-    pair_expert = expert_idx.reshape(-1)                            # (n*k,)
-    pair_gate = gates.reshape(-1).astype(dt)
-    pair_token = jnp.repeat(jnp.arange(n), m.top_k)
-    order = jnp.argsort(pair_expert)                                # stable
-    se, sg, st = pair_expert[order], pair_gate[order], pair_token[order]
-    # position of each pair within its expert group
-    counts = jnp.bincount(se, length=m.num_experts)                 # (E,)
-    starts = jnp.cumsum(counts) - counts                            # (E,)
-    pos_in_expert = jnp.arange(n * m.top_k) - starts[se]
-    keep = pos_in_expert < cap
-    dest = jnp.where(keep, se * cap + pos_in_expert, n * m.top_k)   # overflow slot
-
-    # slot -> token map (small int scatters; dest is unique by construction)
-    n_slots = m.num_experts * cap
-    slot_token = jnp.full((n_slots + 1,), n, jnp.int32).at[dest].set(
-        st, unique_indices=True, mode="drop")[:n_slots]
-    slot_gate = jnp.zeros((n_slots + 1,), dt).at[dest].set(
-        jnp.where(keep, sg, 0), unique_indices=True, mode="drop")[:n_slots]
-
-    # ---- slot-major dispatch (§Perf-2.3): GATHER from the (replicated)
-    # token array with expert-sharded slot indices.  Forward is shard-local;
-    # backward is a partial scatter-add + one (n,d) all-reduce.  The previous
-    # scatter-set formulation replicated its 10 GB/layer cotangent with an
-    # all-gather on deepseek-v2.
-    xt_pad = jnp.concatenate([xt, jnp.zeros((1, d), dt)], axis=0)
-    slot_token_ec = maybe_shard(slot_token.reshape(m.num_experts, cap),
-                                "experts", None)
-    edx = xt_pad[slot_token_ec]                                     # (E, C, d)
-    edx = maybe_shard(edx, "experts", None, "embed")
-
-    gate_w = params["w_gate"].astype(dt)
-    up_w = params["w_up"].astype(dt)
-    down_w = params["w_down"].astype(dt)
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", edx, gate_w)) * jnp.einsum(
-        "ecd,edf->ecf", edx, up_w)
-    h = maybe_shard(h, "experts", None, None)
-    eout = jnp.einsum("ecf,efd->ecd", h, down_w)                    # (E, C, d)
-
-    # ---- slot-major combine (§Perf-2.1): scatter-add from the expert-sharded
-    # slot axis into token space.  The pair-major formulation
-    # (`eout_flat[dest] * gate`) gathers from a sharded operand with
-    # replicated indices, which GSPMD implements by ALL-REDUCING the whole
-    # (n·k, d) gather result — 24 GB/layer on deepseek-v2.  Slot-major keeps
-    # the big operand sharded and all-reduces only the (n, d) output.
-    sg_ec = maybe_shard(slot_gate.reshape(m.num_experts, cap), "experts", None)
-    contrib = eout * sg_ec[..., None]                               # (E, C, d)
-    # NOTE: a token can occupy up to top_k slots -> indices NOT unique here
-    y = jnp.zeros((n + 1, d), dt).at[slot_token_ec].add(contrib, mode="drop")[:n]
-
-    if "shared" in params:
-        sh = params["shared"]
-        hs = jax.nn.silu(jnp.einsum("nd,df->nf", xt, sh["w_gate"].astype(dt)))
-        hs = hs * jnp.einsum("nd,df->nf", xt, sh["w_up"].astype(dt))
-        y = y + jnp.einsum("nf,fd->nd", hs, sh["w_down"].astype(dt))
-
-    return y, aux.astype(jnp.float32)
+    A Mosaic call lowers only where every mesh axis is manual, so where the
+    context mesh has Auto axes the layer runs in a `shard_map` manual over
+    them.  Each device dispatches its own tokens (the batch split over the
+    data axes; a batch they do not divide, as in the decode of fewer
+    sequences than data shards, is whole on every device, as it is outside
+    the layer).  The expert stacks stay split over the `experts` axis
+    (`model`): each device computes its block of the held experts, and of
+    the shared experts' width where that divides, for its tokens, and the
+    parts are summed over the axis.  The counters are reduced over both."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = () if mesh.empty else tuple(mesh.auto_axes)
+    if not auto:
+        return _layer(params, x, m, (), None)
+    daxes = tuple(a for a in data_axes(mesh) if a in auto)
+    if x.shape[0] % math.prod(mesh.shape[a] for a in daxes):
+        daxes = ()
+    eaxis = _expert_axis(mesh, auto, params)
+    xspec = P(daxes or None, None, None)
+    pspec = jax.tree.map(lambda _: P(), params)
+    if eaxis:
+        pspec.update(w_gate=P(eaxis), w_up=P(eaxis), w_down=P(eaxis))
+        sh = params.get("shared")
+        if sh is not None and sh["w_up"].shape[1] % mesh.shape[eaxis] == 0:
+            pspec["shared"] = {"w_gate": P(None, eaxis),
+                               "w_up": P(None, eaxis),
+                               "w_down": P(eaxis, None)}
+    return jax.shard_map(
+        lambda p, xx: _layer(p, xx, m, daxes, eaxis), in_specs=(pspec, xspec),
+        out_specs=(xspec, MoEStats(P(), P(), P())),
+        axis_names=set(auto), check_vma=False)(params, x)

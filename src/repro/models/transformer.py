@@ -22,6 +22,7 @@ from repro import spans
 from repro.models import blocks as blk
 from repro.models.common import split_keys
 from repro.models.config import ModelConfig
+from repro.models.moe import MoEStats
 from repro.models.embeddings import (
     init_embedding, embed_tokens, unembed, sinusoidal_positions, sinusoidal_at)
 from repro.models.norms import init_norm, apply_norm
@@ -107,15 +108,16 @@ def _apply_cross(p, x, enc, cfg):
 
 
 def run_stack(params, x, positions, cfg: ModelConfig, enc=None, collect_cache=False):
-    """Run prefix + scanned blocks. Returns (hidden, aux, caches|None)."""
+    """Run prefix + scanned blocks. Returns (hidden, MoEStats over the
+    layers, caches|None)."""
     prefix_kinds, prefix_moe, pattern, pattern_moe, repeats = _layer_plan(cfg)
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = MoEStats.zeros()
     prefix_caches = []
     for p, kind, moe_layer in zip(params.get("prefix_blocks", []), prefix_kinds, prefix_moe):
         x, aux, cache = blk.block_full(p, x, positions, cfg, kind, moe_layer,
                                        collect_cache=collect_cache)
         x = _apply_cross(p, x, enc, cfg)
-        aux_total += aux
+        aux_total = aux_total.merge(aux)
         prefix_caches.append(cache)
 
     def body(carry, layer_params):
@@ -125,7 +127,7 @@ def run_stack(params, x, positions, cfg: ModelConfig, enc=None, collect_cache=Fa
             x, aux, cache = blk.block_full(p, x, positions, cfg, kind, moe_layer,
                                            collect_cache=collect_cache)
             x = _apply_cross(p, x, enc, cfg)
-            aux_total += aux
+            aux_total = aux_total.merge(aux)
             caches.append(cache)
         out = tuple(caches) if collect_cache else None
         return (x, aux_total), out
@@ -236,14 +238,20 @@ def forward(params, batch, cfg: ModelConfig):
 
 
 def loss_fn(params, batch, cfg: ModelConfig):
-    """Mean next-token cross entropy (+ MoE aux). labels use -1 as mask."""
-    hidden, aux, offset, _ = forward(params, batch, cfg)
+    """Mean next-token cross entropy (+ MoE aux). labels use -1 as mask.
+    A model with expert layers also reports the dispatch counters
+    (`spans.COUNTERS`) of the call."""
+    hidden, stats, offset, _ = forward(params, batch, cfg)
     labels = batch["labels"]
     if offset:  # VLM: prefix patch positions carry no labels
         pad = jnp.full((labels.shape[0], offset), -1, labels.dtype)
         labels = jnp.concatenate([pad, labels], axis=1)
     loss = token_loss(params, hidden, labels, cfg)
-    return loss + aux, {"xent": loss, "aux": aux}
+    metrics = {"xent": loss, "aux": stats.aux}
+    if cfg.moe is not None:
+        metrics[spans.EXPERT_ROWS] = stats.rows
+        metrics[spans.EXPERT_LOAD_MAX] = stats.load_max
+    return loss + stats.aux, metrics
 
 
 def prefill(params, batch, cfg: ModelConfig):

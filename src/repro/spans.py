@@ -2,8 +2,9 @@
 
 The training loop wraps each phase of a step in a host span
 (`jax.profiler.TraceAnnotation`) named here, with the 1-based step as its
-`step` argument; the model wraps its three big blocks in
-`jax.named_scope`s, and the attention core in one more inside `attention`.
+`step` argument; the model wraps its big blocks in `jax.named_scope`s,
+the attention core in one more inside `attention`, and the experts'
+grouped matmuls in one inside `moe`.
 All of them land in the JAX profiler's own trace, on the device trace's
 clock, and cost one level check each while no trace is active.
 """
@@ -31,10 +32,20 @@ LOOP = (BATCH, LOOKUP, PUT, LR, DISPATCH, READBACK, FENCE, CONTROLLER,
 ATTENTION = "attention"
 MLP = "mlp"
 HEAD = "head"
-SCOPES = (ATTENTION, MLP, HEAD)
+SCOPES = (ATTENTION, MLP, HEAD)   # every decoder model's step holds these
+# the whole expert layer (`models/moe.py`), in models with expert layers
+MOE = "moe"
 # nested in ATTENTION: the score-softmax-output core (`ops.attention_core`),
 # without the projections and RoPE
 ATTENTION_CORE = "attention_core"
+# nested in MOE: the experts' three grouped matmuls (`ops.grouped_matmul`)
+EXPERT_MATMUL = "expert_matmul"
+
+# counters the step returns for a model with expert layers, read back with
+# the statistics and kept in the run's history under these names
+EXPERT_ROWS = "expert_rows"          # token-expert pairs on held experts
+EXPERT_LOAD_MAX = "expert_load_max"  # largest held expert's pairs / mean
+COUNTERS = (EXPERT_ROWS, EXPERT_LOAD_MAX)
 
 
 def span(name: str, step: int):

@@ -264,3 +264,104 @@ for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
 print("OK")
 """, devices=4)
     assert "OK" in out
+
+
+# MLA's widths (query/key 192 = 128 + 64, value 128) scaled down, and an
+# explicit softmax scale (YaRN's factor on 1/sqrt of the query width)
+MLA_CASES = [(1, 256, 2, 48, 32, 0.3), (2, 256, 4, 24, 16, 1.59 / 24 ** 0.5)]
+
+
+def _mla_qkv(b, t, h, dqk, dv):
+    k1, k2, k3, k4 = jax.random.split(KEY, 4)
+    return (jax.random.normal(k1, (b, t, h, dqk)),
+            jax.random.normal(k2, (b, t, h, dqk)),
+            jax.random.normal(k3, (b, t, h, dv)),
+            jax.random.normal(k4, (b, t, h, dv)))
+
+
+@pytest.mark.parametrize("b,t,h,dqk,dv,scale", MLA_CASES)
+def test_flash_attention_value_width_and_scale(b, t, h, dqk, dv, scale):
+    """The kernels at a value width unequal to the query/key width and an
+    explicit scale, forward and the three gradients, against the jnp path
+    (`sdpa_self`, the CPU path of `attention_core`)."""
+    from repro.models.attention import sdpa_self
+    q, k, v, w = _mla_qkv(b, t, h, dqk, dv)
+
+    def loss(core):
+        return lambda q, k, v: jnp.sum(core(q, k, v) * w)
+
+    def flash(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True, scale=scale,
+                                   block_q=128, block_kv=128)
+
+    def jnp_core(q, k, v):
+        return sdpa_self(q, k, v, causal=True, window=0, softcap=0.0,
+                         scale=scale)
+
+    out = flash(q, k, v)
+    assert out.shape == (b, t, h, dv)
+    _assert_close(out, jnp_core(q, k, v), FLASH_TOL[jnp.float32])
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(jnp_core), (0, 1, 2))(q, k, v)
+    for g, x in zip(got, (q, k, v)):
+        assert g.shape == x.shape
+    for a, b_ in zip(got, want):
+        _assert_close(a, b_, FLASH_TOL[jnp.float32])
+
+
+def test_flash_attention_default_scale_is_unchanged():
+    """Equal widths and no scale: the same configuration as before the
+    value width and scale became arguments, so the equal-width callers
+    (every dense model's attention) lower to the same kernels."""
+    from repro.kernels import flash_attention as fa
+    q, k, v = _qkv(1, 256, 4, 2, 64, jnp.float32)
+    *_, c = fa._flat(q, k, v, True, 0, 0.0, 128, 128, None)
+    *_, c_explicit = fa._flat(q, k, v, True, 0, 0.0, 128, 128, None,
+                              1.0 / 64 ** 0.5)
+    assert c == c_explicit and c.scale == 1.0 / 8.0
+
+
+@pytest.mark.parametrize("sizes", [(50, 0, 70, 42), (192, 0, 0, 0),
+                                   (0, 0, 0, 0)])
+def test_grouped_matmul_kernels_match_ragged_dot(sizes):
+    """The megablox kernels (interpret mode; operands rounded to bf16 as on
+    the MXU) against `jax.lax.ragged_dot` over the same bf16-rounded
+    operands, forward and both gradients, rows past the groups masked as
+    the expert layer masks them."""
+    from repro.kernels.grouped_matmul import grouped_matmul
+    k1, k2, k3 = jax.random.split(KEY, 3)
+    m, kk, n = 192, 128, 200
+    x = jax.random.normal(k1, (m, kk)).astype(jnp.bfloat16).astype(
+        jnp.float32)
+    w = jax.random.normal(k2, (4, kk, n)).astype(jnp.bfloat16).astype(
+        jnp.float32)
+    c = jax.random.normal(k3, (m, n))
+    gs = jnp.asarray(sizes, jnp.int32)
+    valid = (jnp.arange(m) < sum(sizes))[:, None]
+
+    def loss(mm):
+        return lambda x, w: jnp.sum(
+            jnp.where(valid, mm(jnp.where(valid, x, 0), w), 0) * c)
+
+    kern = loss(lambda x, w: grouped_matmul(x, w, gs, interpret=True))
+    want = loss(lambda x, w: jax.lax.ragged_dot(x, w, gs))
+    _assert_close(kern(x, w), want(x, w), 1e-3 * (1 + abs(float(want(x, w)))))
+    for a, b_ in zip(jax.grad(kern, (0, 1))(x, w),
+                     jax.grad(want, (0, 1))(x, w)):
+        # the cotangent enters the MXU as bf16 too
+        _assert_close(a, b_, 0.05 * (1 + float(jnp.max(jnp.abs(b_)))))
+
+
+def test_grouped_matmul_dispatch(monkeypatch):
+    """ragged_dot on the CPU; the megablox kernels where the backend is
+    TPU; `flat_dispatch_info` reports which."""
+    x = jnp.ones((128, 128))
+    w = jnp.ones((2, 128, 128))
+    gs = jnp.asarray([100, 28], jnp.int32)
+    assert ops.flat_dispatch_info()["grouped_matmul"] == "ragged-dot"
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda x, w: ops.grouped_matmul(x, w, gs))(x, w))
+    monkeypatch.setattr(ops, "_backend_is_tpu", lambda: True)
+    assert ops.flat_dispatch_info()["grouped_matmul"] == "pallas-megablox"
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda x, w: ops.grouped_matmul(x, w, gs))(x, w))

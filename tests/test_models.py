@@ -134,9 +134,194 @@ def test_full_config_param_counts():
 def test_moe_aux_loss_and_balance():
     from repro.models.moe import init_moe, moe_apply
     from repro.models.config import MoEConfig
-    m = MoEConfig(num_experts=4, top_k=2, d_expert=32, capacity_factor=2.0)
+    m = MoEConfig(num_experts=4, top_k=2, d_expert=32)
     p = init_moe(jax.random.PRNGKey(1), 16, m, "swiglu", jnp.float32)
     x = jax.random.normal(KEY, (2, 8, 16))
-    out, aux = moe_apply(p, x, m)
+    out, stats = moe_apply(p, x, m)
     assert out.shape == x.shape
-    assert float(aux) > 0  # switch aux loss >= coef * 1.0 at balance
+    # the sequence-level balance loss is >= coef * 1.0, its value at balance
+    assert float(stats.aux) >= m.router_aux_coef * (1 - 1e-6)
+    assert float(stats.rows) == 2 * 8 * 2
+
+
+def _moe_dense(p, x, m):
+    """Plain per-token form of the expert layer: every held expert applied
+    to every token, weighted by its gate (0 where not among the token's
+    top k), plus the shared experts."""
+    from repro.models import moe
+    probs, experts, gates = moe.route(p, x, m)
+    full = jnp.sum(jax.nn.one_hot(experts, m.num_experts) * gates[..., None],
+                   axis=-2)                                  # (b, t, E)
+    y = moe._shared(p, x) if "shared" in p else jnp.zeros_like(x)
+    for e in range(m.held):
+        h = jax.nn.silu(x @ p["w_gate"][e]) * (x @ p["w_up"][e])
+        y = y + full[..., e:e + 1] * (h @ p["w_down"][e])
+    return y
+
+
+def _moe_case(m, d=16, key=1):
+    from repro.models.moe import init_moe
+    p = init_moe(jax.random.PRNGKey(key), d, m, "swiglu", jnp.float32)
+    x = jax.random.normal(KEY, (2, 24, d))
+    return p, x
+
+
+def test_dropless_moe_keeps_every_token_when_all_route_to_one_expert():
+    """Every token's top k holds expert 0: all 48 tokens go to it, where
+    the old capacity (1.25·n·k/E = 30) would have dropped 18, and the
+    layer equals its plain per-token form."""
+    from repro.models.config import MoEConfig
+    from repro.models.moe import moe_apply
+    m = MoEConfig(num_experts=4, top_k=2, d_expert=32, num_shared_experts=1,
+                  shared_d_expert=8)
+    p, x = _moe_case(m)
+    x = jnp.abs(x)
+    p["router"] = p["router"].at[:, 0].set(1.0)
+    with jax.default_matmul_precision("highest"):
+        out, stats = moe_apply(p, x, m)
+        want = _moe_dense(p, x, m)
+    assert float(stats.rows) == 2 * 24 * 2
+    # expert 0 holds all 48 of the 96 pairs: twice the mean of 24
+    assert float(stats.load_max) == 2.0
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("normalize", [False, True])
+def test_moe_gates_normalised_only_where_the_config_says(normalize):
+    from repro.models import moe
+    from repro.models.config import MoEConfig
+    m = MoEConfig(num_experts=8, top_k=3, d_expert=16,
+                  normalize_gates=normalize, routed_scaling_factor=2.0)
+    p, x = _moe_case(m)
+    probs, experts, gates = moe.route(p, x, m)
+    top = jnp.take_along_axis(probs, experts, axis=-1)
+    if normalize:
+        want = 2.0 * top / jnp.sum(top, axis=-1, keepdims=True)
+    else:
+        want = 2.0 * top          # DeepSeek-V2: the router's probabilities
+    np.testing.assert_allclose(np.asarray(gates), np.asarray(want),
+                               rtol=1e-6)
+    assert bool(jnp.all(jnp.sum(gates, -1) < 2.0)) != normalize
+
+
+def test_moe_expert_stacks_stay_split_over_the_model_axis(subproc):
+    """On a 2x2 (data, model) mesh of Auto axes each device holds its half
+    of the expert stacks and of the shared width (as `param_pspecs` lays
+    them out) and nothing gathers them; the layer, its counters and its
+    gradients equal the unsharded layer's."""
+    out = subproc("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding
+from repro.distributed.params import param_pspecs
+from repro.models.config import MoEConfig
+from repro.models.moe import init_moe, moe_apply
+m = MoEConfig(num_experts=8, top_k=3, d_expert=16, num_shared_experts=2,
+              shared_d_expert=8, normalize_gates=False)
+p = init_moe(jax.random.PRNGKey(1), 32, m, "swiglu", jnp.float32)
+x = jax.random.normal(jax.random.PRNGKey(2), (4, 16, 32))
+w = jax.random.normal(jax.random.PRNGKey(3), x.shape)
+def loss(p, x):
+    y, st = moe_apply(p, x, m)
+    return jnp.sum(y * w) + st.aux, st
+f = jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))
+with jax.default_matmul_precision("highest"):
+    want = f(p, x)
+    mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    with jax.set_mesh(mesh):
+        ps = jax.device_put(p, jax.tree.map(
+            lambda s: NamedSharding(mesh, s), param_pspecs(p, mesh)))
+        assert ps["w_up"].sharding.spec[0] == "model"
+        assert ps["shared"]["w_up"].sharding.spec[1] == "model"
+        hlo = f.lower(ps, x).compile().as_text()
+        got = f(ps, x)
+assert "all-gather" not in hlo, [l for l in hlo.splitlines()
+                                 if "all-gather" in l][:3]
+# each data shard dispatches its own two sequences: the load ratio is the
+# larger of theirs
+(gv, gst), gg = got
+(wv, wst), wg = want
+with jax.default_matmul_precision("highest"):
+    halves = [float(moe_apply(p, x[i:i + 2], m)[1].load_max) for i in (0, 2)]
+assert float(gst.load_max) == max(halves), (gst.load_max, halves)
+got = (gv, gst._replace(load_max=0.0), gg)
+want = (wv, wst._replace(load_max=0.0), wg)
+for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                               atol=1e-5)
+print("OK")
+""", devices=4)
+    assert "OK" in out
+
+
+def test_expert_shares_add_up_to_the_uncut_layer():
+    """A 64-expert layer over 8 chips: chip j holds experts 8j..8j+7 (its
+    router columns first).  The routed parts of all 8 shares, plus the
+    shared experts counted once, add up to the uncut layer; the pairs the
+    shares compute add up to every pair."""
+    from repro.models import moe
+    from repro.models.config import MoEConfig
+    full = MoEConfig(num_experts=64, top_k=6, d_expert=16,
+                     num_shared_experts=2, shared_d_expert=16,
+                     normalize_gates=False)
+    share = MoEConfig(num_experts=64, top_k=6, d_expert=16,
+                      num_shared_experts=2, shared_d_expert=16,
+                      normalize_gates=False, held_experts=8)
+    p, x = _moe_case(full, d=32)
+    with jax.default_matmul_precision("highest"):
+        want, stats = moe.moe_apply(p, x, full)
+        shared = moe._shared(p, x)
+        total, rows = shared, 0.0
+        for j in range(8):
+            cols = jnp.concatenate([jnp.arange(8 * j, 8 * j + 8),
+                                    jnp.arange(0, 8 * j),
+                                    jnp.arange(8 * j + 8, 64)])
+            pj = dict(p, router=p["router"][:, cols],
+                      **{w: p[w][8 * j:8 * j + 8]
+                         for w in ("w_gate", "w_up", "w_down")})
+            yj, sj = moe.moe_apply(pj, x, share)
+            total = total + (yj - shared)
+            rows += float(sj.rows)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                               rtol=1e-4, atol=1e-6)
+    assert rows == float(stats.rows) == 2 * 24 * 6
+
+
+def test_yarn_frequencies_and_scale_by_hand():
+    """DeepSeek-V2-Lite's YaRN (RoPE width 64, θ 10000, factor 40 over the
+    original 4,096 positions, β_fast 32, β_slow 1, mscale = mscale_all_dim
+    = 0.707), worked out from the published formula: the correction
+    dimensions are ⌊10.47⌋ = 10 and ⌈22.51⌉ = 23, so frequencies 0-10 keep
+    θ^(-2i/64), 23-31 are divided by 40, and i = 16 sits 6/13 of the way;
+    the softmax scale is (0.1·0.707·ln 40 + 1)² / √192 = 0.1147214; cos
+    and sin keep a factor of 1."""
+    from repro.models import embeddings, mla
+    from repro.configs import get_config
+    m = get_config("deepseek-v2-lite-16b").mla
+    f = np.asarray(embeddings.yarn_frequencies(64, 1e4, 40.0, 4096, 32.0,
+                                               1.0))
+    base = 1e4 ** (-np.arange(0, 64, 2) / 64)
+    np.testing.assert_allclose(f[:11], base[:11], rtol=1e-6)
+    np.testing.assert_allclose(f[23:], base[23:] / 40, rtol=1e-6)
+    np.testing.assert_allclose(f[10], 0.05623413, rtol=1e-6)
+    np.testing.assert_allclose(f[16], 0.0055, rtol=1e-5)
+    assert mla.softmax_scale(m) == pytest.approx(0.11472139, rel=1e-7)
+    assert embeddings.yarn_mscale(40.0, 0.707) == pytest.approx(1.2608038)
+    x = jax.random.normal(KEY, (1, 8, 2, 64))
+    pos = jnp.arange(8)[None]
+    np.testing.assert_allclose(
+        np.asarray(mla._rope(x, pos, m, 1e4)),
+        np.asarray(embeddings.apply_rope(x, pos, 1e4, freqs=jnp.asarray(f))),
+        rtol=1e-6)
+
+
+def test_mla_rope_base_comes_from_the_config():
+    from repro.models import mla
+    from repro.models.config import MLAConfig
+    m = MLAConfig()
+    x = jax.random.normal(KEY, (1, 8, 2, 64))
+    pos = jnp.arange(8)[None]
+    a = mla._rope(x, pos, m, 10000.0)
+    b = mla._rope(x, pos, m, 500000.0)
+    assert not np.allclose(np.asarray(a), np.asarray(b))

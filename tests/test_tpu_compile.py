@@ -14,6 +14,7 @@ one process at a time may load the TPU library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,9 @@ import pytest
 from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 V5E_HBM_BYTES = 15.75e9        # usable HBM of one v5e chip (16 GB part)
+# what the allocator of one v5e chip hands out (`memory_stats()["bytes_limit"]`
+# read on the chip: 15.75 GiB)
+V5E_ALLOCATOR_BYTES = 16_909_336_064
 BUCKET = (4 << 20) // 4        # one 4 MiB f32 flat bucket
 FLASH_KERNELS = ("flash_attention_fwd", "flash_attention_dkv",
                  "flash_attention_dq")
@@ -111,10 +115,11 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
 
 def _compile_step(topo, arch, step_impl, stats_impl, params_impl, *,
-                  workers, accum, micro, seq=2048, layers=None):
+                  workers, accum, micro, seq=2048, layers=None,
+                  overrides=None):
     """Compile one full-width train step (remat full; `layers` deep where
-    given) on a `workers`-chip data mesh of the described topology;
-    returns the compiled step."""
+    given, `overrides` of the model config applied) on a `workers`-chip
+    data mesh of the described topology; returns the compiled step."""
     from repro.configs import get_config
     from repro.distributed.train_step import (
         _opt_like_for, make_accum_norm_step, make_fsdp_norm_step)
@@ -122,7 +127,7 @@ def _compile_step(topo, arch, step_impl, stats_impl, params_impl, *,
     from repro.optim.adamw import AdamWConfig
     mesh = Mesh(np.array(topo.devices[:workers]).reshape(workers, 1),
                 ("data", "model"), axis_types=(AxisType.Auto,) * 2)
-    cfg = get_config(arch).replace(remat="full")
+    cfg = get_config(arch).replace(remat="full", **(overrides or {}))
     model = build_model(cfg if layers is None else cfg.replace(
         num_layers=layers))
     params_like = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -170,6 +175,48 @@ def test_phi3_mini_step_runs_flash_attention(topo, as_tpu):
         assert kernel in text, kernel
     assert "f32[2,32,512,2048]" not in text
     assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
+
+
+def test_deepseek_v2_lite_step_fits_one_v5e(topo, as_tpu):
+    """The benchmark's DeepSeek-V2-Lite cut (`deepseek-v2-lite-1chip`: 5
+    layers, 8 of 64 experts held, 12,800 vocabulary rows, at published
+    widths), its top-rung ACCUM-NORM step as its cell runs it
+    (`adaptive-cross-4k`: M=8 x micro 2, seq 4096, tree-resident weights):
+    MLA in the flash kernels at query/key width 192 and value width 128,
+    the experts in the megablox grouped-matmul kernels, no jnp score
+    matrix, temporaries plus arguments within one chip's HBM, and room in
+    the chip's allocator for one more copy of the weights (the benchmark
+    copies them out after step 2 while that step may still hold its
+    temporaries)."""
+    import json
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip")
+    with open(os.path.join(bench, "configs",
+                           "deepseek-v2-lite-1chip.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(bench, "traffic", "adaptive-cross-4k.json")) as f:
+        traffic = json.load(f)
+    program, train = config["program"], config["train"]
+    overrides = dict(program["overrides"])
+    layers = overrides.pop("num_layers")
+    micro = traffic["micro_batch"]
+    compiled = _compile_step(topo, program["arch"], train["step_impl"],
+                             train["stats_impl"], train["params_impl"],
+                             workers=1, accum=traffic["rungs"][-1] // micro,
+                             micro=micro, seq=traffic["seq_len"],
+                             layers=layers, overrides=overrides)
+    text = compiled.as_text()
+    for kernel in FLASH_KERNELS:
+        assert kernel in text, kernel
+    assert not re.search(r"f32\[\d+,16,4096,4096\]", text)
+    # gate, up and down: forward, recomputed and the input's gradient
+    # (`gmm`), the weights' gradient (`tgmm`)
+    assert len(re.findall(r"%gmm(?:\.\d+)? = ", text)) == 9
+    assert len(re.findall(r"%tgmm(?:\.\d+)? = ", text)) == 3
+    assert "ragged-dot" not in text
+    weights = compiled.memory_analysis().argument_size_in_bytes / 3
+    assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
+    assert _hbm_bytes(compiled) + weights <= V5E_ALLOCATOR_BYTES
 
 
 @pytest.mark.parametrize("step_impl", ["fsdp_norm", "accum_norm"])
