@@ -1,7 +1,7 @@
 """The two candidates for a dropless expert layer's grouped matmuls, timed
 at a cell's shapes on the chip, run by hand:
 
-    python3 benchmarks/expert_matmul_candidates.py [--rows 49152]
+    python3 benchmarks/expert_matmul_candidates.py [--rows 12288]
 
 The three products of the held SwiGLU experts (gate and up, d -> f; down,
 f -> d) over rows sorted into `--groups` groups of uneven size, forward
@@ -10,7 +10,8 @@ and backward, as `models/moe.py` runs them: the megablox Pallas kernels
 dot).  Prints one JSON line per candidate with the median of `--iters`
 fenced calls.  Needs a TPU; the shapes default to
 deepseek-v2-lite-1chip.adaptive-cross-4k's (8,192 tokens a microbatch,
-6 experts a token, 8 of 64 held: 49,152 rows buffered, ~6,144 filled).
+6 experts a token, 8 of 64 held: a buffer chunk of 12,288 rows,
+`moe.buffer_chunks`, ~6,144 filled).
 """
 
 from __future__ import annotations
@@ -28,7 +29,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--rows", type=int, default=49152)
+    ap.add_argument("--rows", type=int, default=12288)
     ap.add_argument("--filled", type=int, default=6144)
     ap.add_argument("--groups", type=int, default=8)
     ap.add_argument("--d", type=int, default=2048)

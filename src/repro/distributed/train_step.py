@@ -188,8 +188,8 @@ def _accumulate(loss_fn, params, batch, track_micro_sqnorm: bool,
     """lax.scan over the M stacked microbatches; returns (mean grads g,
     mean loss, extras, Σ_m ‖ĝ^m‖² if tracked, effective microbatch count,
     valid-token weight).  `extras` holds the mean aux loss and the
-    `counters` the loss reports: expert pairs summed over the microbatches,
-    the load ratio their max.
+    `counters` the loss reports: expert pairs and buffer rows summed over
+    the microbatches, the load ratio their max.
 
     `loss_fn(params, microbatch) -> (loss, metrics)`; `params` is whatever
     the loss differentiates — the model pytree, or a tuple of flat-resident
@@ -214,11 +214,11 @@ def _accumulate(loss_fn, params, batch, track_micro_sqnorm: bool,
             # the Σ_m ‖ĝ^m‖² used by the accumulation-variance estimator
             acc_sq = acc_sq + jnp.where(w > 0, tree_sqnorm(g), 0.0)
         acc_ex = dict(acc_ex, aux=acc_ex["aux"] + w * metrics["aux"])
-        if spans.EXPERT_ROWS in counters:
-            acc_ex[spans.EXPERT_ROWS] += metrics[spans.EXPERT_ROWS]
-        if spans.EXPERT_LOAD_MAX in counters:
-            acc_ex[spans.EXPERT_LOAD_MAX] = jnp.maximum(
-                acc_ex[spans.EXPERT_LOAD_MAX], metrics[spans.EXPERT_LOAD_MAX])
+        for name in counters:
+            acc_ex[name] = (
+                jnp.maximum(acc_ex[name], metrics[name])
+                if name == spans.EXPERT_LOAD_MAX
+                else acc_ex[name] + metrics[name])
         return (acc_g, acc_loss + w * loss, acc_ex, acc_sq, acc_w + w,
                 acc_m + (w > 0)), None
 
@@ -354,13 +354,10 @@ def make_fsdp_norm_step(model, opt_cfg: AdamWConfig, mesh, *,
                 var_l1, gsq = worker_variance_stats(g_j, g, daxes)
             loss = jax.lax.psum(loss * w_j, daxes) / w_sum
             aux = jax.lax.psum(extras["aux"] * w_j, daxes) / w_sum
-            counted = {}
-            if spans.EXPERT_ROWS in counters:
-                counted[spans.EXPERT_ROWS] = jax.lax.psum(
-                    extras[spans.EXPERT_ROWS], daxes)
-            if spans.EXPERT_LOAD_MAX in counters:
-                counted[spans.EXPERT_LOAD_MAX] = jax.lax.pmax(
-                    extras[spans.EXPERT_LOAD_MAX], daxes)
+            counted = {
+                name: (jax.lax.pmax if name == spans.EXPERT_LOAD_MAX
+                       else jax.lax.psum)(extras[name], daxes)
+                for name in counters}
             if params_impl == "flat":
                 if stats_impl == "flat":
                     # the input params ARE the worker's param shard; the

@@ -186,16 +186,14 @@ def init_train_state(model, key, mesh, wrap, p_specs, o_specs, *,
 
 def _merge_counters(counted: dict, metrics: dict) -> dict:
     """The step's dispatch counters (`spans.COUNTERS`, present for a model
-    with expert layers) read back and folded into `counted`: pairs add
-    over sub-steps, the load ratio is their max."""
+    with expert layers) read back and folded into `counted`: pairs and
+    buffer rows add over sub-steps, the load ratio is their max."""
     out = dict(counted)
-    if spans.EXPERT_ROWS in metrics:
-        out[spans.EXPERT_ROWS] = (out.get(spans.EXPERT_ROWS, 0.0)
-                                  + float(metrics[spans.EXPERT_ROWS]))
-    if spans.EXPERT_LOAD_MAX in metrics:
-        out[spans.EXPERT_LOAD_MAX] = max(
-            out.get(spans.EXPERT_LOAD_MAX, 0.0),
-            float(metrics[spans.EXPERT_LOAD_MAX]))
+    for name in spans.COUNTERS:
+        if name in metrics:
+            value, seen = float(metrics[name]), out.get(name, 0.0)
+            out[name] = (max(seen, value) if name == spans.EXPERT_LOAD_MAX
+                         else seen + value)
     return out
 
 

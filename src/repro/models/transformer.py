@@ -250,6 +250,7 @@ def loss_fn(params, batch, cfg: ModelConfig):
     metrics = {"xent": loss, "aux": stats.aux}
     if cfg.moe is not None:
         metrics[spans.EXPERT_ROWS] = stats.rows
+        metrics[spans.EXPERT_BUFFER_ROWS] = stats.buffer_rows
         metrics[spans.EXPERT_LOAD_MAX] = stats.load_max
     return loss + stats.aux, metrics
 

@@ -44,8 +44,9 @@ EXPERT_MATMUL = "expert_matmul"
 # counters the step returns for a model with expert layers, read back with
 # the statistics and kept in the run's history under these names
 EXPERT_ROWS = "expert_rows"          # token-expert pairs on held experts
+EXPERT_BUFFER_ROWS = "expert_buffer_rows"  # dispatch buffer rows they took
 EXPERT_LOAD_MAX = "expert_load_max"  # largest held expert's pairs / mean
-COUNTERS = (EXPERT_ROWS, EXPERT_LOAD_MAX)
+COUNTERS = (EXPERT_ROWS, EXPERT_BUFFER_ROWS, EXPERT_LOAD_MAX)
 
 
 def span(name: str, step: int):

@@ -322,7 +322,7 @@ def test_flash_attention_default_scale_is_unchanged():
 
 
 @pytest.mark.parametrize("sizes", [(50, 0, 70, 42), (192, 0, 0, 0),
-                                   (0, 0, 0, 0)])
+                                   (0, 0, 0, 0), (0, 0, 130, 30)])
 def test_grouped_matmul_kernels_match_ragged_dot(sizes):
     """The megablox kernels (interpret mode; operands rounded to bf16 as on
     the MXU) against `jax.lax.ragged_dot` over the same bf16-rounded

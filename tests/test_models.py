@@ -245,8 +245,10 @@ assert "all-gather" not in hlo, [l for l in hlo.splitlines()
 with jax.default_matmul_precision("highest"):
     halves = [float(moe_apply(p, x[i:i + 2], m)[1].load_max) for i in (0, 2)]
 assert float(gst.load_max) == max(halves), (gst.load_max, halves)
-got = (gv, gst._replace(load_max=0.0), gg)
-want = (wv, wst._replace(load_max=0.0), wg)
+# each of the 4 devices buffers its own worst case: 32 tokens x 3 pairs
+assert float(gst.buffer_rows) == 4 * 32 * 3, gst.buffer_rows
+got = (gv, gst._replace(load_max=0.0, buffer_rows=0.0), gg)
+want = (wv, wst._replace(load_max=0.0, buffer_rows=0.0), wg)
 for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                atol=1e-5)
@@ -286,6 +288,142 @@ def test_expert_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                rtol=1e-4, atol=1e-6)
     assert rows == float(stats.rows) == 2 * 24 * 6
+
+
+# 2 of 16 experts held, top 2, 1,024 tokens: the buffer's worst case is
+# 2,048 rows, cut into 4 chunks of 512
+CHUNKED = dict(num_experts=16, top_k=2, d_expert=32, held_experts=2,
+               num_shared_experts=1, shared_d_expert=8)
+
+
+def _routed_to_held(m, filled: int, d=16):
+    """The layer's weights and (4, 256, d) tokens of which the first
+    `filled` send both their pairs to held experts 0 and 1 and the rest
+    none: 2·filled held pairs."""
+    from repro.models.moe import init_moe
+    p = init_moe(jax.random.PRNGKey(1), d, m, "swiglu", jnp.float32)
+    p["router"] = p["router"].at[:, 0].set(1.0).at[:, 1].set(0.9)
+    x = jnp.abs(jax.random.normal(KEY, (4 * 256, d)))
+    x = jnp.where(jnp.arange(4 * 256)[:, None] < filled, x, -x)
+    return p, x.reshape(4, 256, d)
+
+
+@pytest.mark.parametrize("filled", [128, 384, 640, 1024],
+                         ids=["one-chunk", "two-chunks", "three-chunks",
+                              "all-to-held-fallback"])
+def test_chunked_moe_equals_the_worst_case_buffer(monkeypatch, filled):
+    """The layer runs the first ceil(pairs / 512) chunks of its buffer;
+    output, counters and gradients (tokens, router, the three expert
+    stacks) equal the layer forced to one worst-case buffer of 2,048
+    rows, down to all pairs on the held experts, where every chunk runs."""
+    from repro.models import moe
+    from repro.models.config import MoEConfig
+    m = MoEConfig(**CHUNKED)
+    p, x = _routed_to_held(m, filled)
+    w = jax.random.normal(jax.random.PRNGKey(3), x.shape)
+
+    def loss(p, x):
+        y, st = moe.moe_apply(p, x, m)
+        return jnp.sum(y * w) + st.aux, (y, st)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (y, st)), g = jax.jit(
+            jax.value_and_grad(loss, (0, 1), has_aux=True))(p, x)
+        # undifferentiated, as in serving's prefill
+        plain = jax.jit(lambda p, x: moe.moe_apply(p, x, m)[0])(p, x)
+        monkeypatch.setattr(moe, "buffer_chunks",
+                            lambda n, k, held, e: (n * min(k, held), 1))
+        (_, (wy, wst)), wg = jax.jit(
+            jax.value_and_grad(loss, (0, 1), has_aux=True))(p, x)
+    assert float(st.rows) == float(wst.rows) == 2 * filled
+    assert float(st.buffer_rows) == 512 * -(-2 * filled // 512)
+    assert float(wst.buffer_rows) == 2048
+    for out in (y, plain):
+        np.testing.assert_allclose(np.asarray(out), np.asarray(wy),
+                                   rtol=1e-5, atol=1e-6)
+    assert float(st.aux) == float(wst.aux)
+    assert float(st.load_max) == float(wst.load_max)
+    (gp, gx), (wgp, wgx) = g, wg
+    for a, b in [(gx, wgx)] + [(gp[k], wgp[k]) for k in
+                               ("router", "w_gate", "w_up", "w_down")]:
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((8192, 6, 8, 64), (12288, 4)),     # the DeepSeek-V2-Lite cell
+    ((1024, 2, 2, 16), (512, 4)),
+    ((8192, 6, 64, 64), (49152, 1)),    # every expert held
+    ((64, 3, 4, 8), (192, 1)),          # the smoke block: 1.5n < 1.875n
+    ((8, 6, 8, 64), (48, 1)),           # a decode step
+], ids=["deepseek-cell", "constructed", "all-held", "smoke", "decode"])
+def test_buffer_chunks_follow_the_shapes(shape, want):
+    from repro.models.moe import buffer_chunks
+    assert buffer_chunks(*shape) == want
+
+
+@pytest.mark.parametrize("held", [16, 2], ids=["one-chunk", "chunked"])
+def test_one_chunk_layer_traces_without_control_flow(held):
+    """Where the shapes give one chunk the layer is the single worst-case
+    buffer, with no `cond` and no custom gradient; where they give more,
+    the chunks after the first run under a `cond`."""
+    from repro.models.config import MoEConfig
+    from repro.models.moe import moe_apply
+    m = MoEConfig(**dict(CHUNKED, held_experts=held))
+    p, x = _routed_to_held(m, 384)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p, x: jnp.sum(moe_apply(p, x, m)[0]), (0, 1)))(p, x))
+    fwd = str(jax.make_jaxpr(lambda p, x: moe_apply(p, x, m))(p, x))
+    chunked = held < m.num_experts
+    assert ("cond[" in text) == chunked
+    assert ("custom_vjp_call" in fwd) == chunked
+
+
+def test_chunked_moe_under_the_model_axis(subproc):
+    """On a 2x2 (data, model) mesh each device cuts its own buffer: the
+    device that holds experts 0-3 runs both of its chunks for the pairs
+    every token sends to experts 0 and 1, the other one chunk; the layer
+    and its gradients equal the unsharded layer's with one worst-case
+    buffer."""
+    out = subproc("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding
+from repro.distributed.params import param_pspecs
+from repro.models import moe
+from repro.models.config import MoEConfig
+from repro.models.moe import buffer_chunks, init_moe, moe_apply
+m = MoEConfig(num_experts=32, top_k=2, d_expert=16, held_experts=8,
+              num_shared_experts=1, shared_d_expert=8)
+# per device: 512 tokens, 4 of 32 experts held
+assert buffer_chunks(512, 2, 4, 32) == (512, 2)
+p = init_moe(jax.random.PRNGKey(1), 32, m, "swiglu", jnp.float32)
+p["router"] = p["router"].at[:, 0].set(1.0).at[:, 1].set(0.9)
+x = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (4, 256, 32)))
+w = jax.random.normal(jax.random.PRNGKey(3), x.shape)
+def loss(p, x):
+    y, st = moe_apply(p, x, m)
+    return jnp.sum(y * w) + st.aux, st
+f = jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))
+with jax.default_matmul_precision("highest"):
+    mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    with jax.set_mesh(mesh):
+        ps = jax.device_put(p, jax.tree.map(
+            lambda s: NamedSharding(mesh, s), param_pspecs(p, mesh)))
+        got = f(ps, x)
+    moe.buffer_chunks = lambda n, k, held, e: (n * min(k, held), 1)
+    want = jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(p, x)
+(gv, gst), gg = got
+(wv, wst), wg = want
+assert float(gst.rows) == float(wst.rows) == 2 * 1024
+# two data shards: 2 chunks of 512 on the first model device, 1 on the other
+assert float(gst.buffer_rows) == 2 * (1024 + 512), gst.buffer_rows
+for a, b in zip(jax.tree.leaves((gv, gg)), jax.tree.leaves((wv, wg))):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                               atol=1e-5)
+print("OK")
+""", devices=4)
+    assert "OK" in out
 
 
 def test_yarn_frequencies_and_scale_by_hand():

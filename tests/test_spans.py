@@ -137,3 +137,25 @@ def test_model_scopes_name_the_step_program():
                 if re.search(rf"(?:^|[/(]){scope}(?:[)/]|$)", n)]
         assert any("transpose(" in n for n in held), scope
         assert any("transpose(" not in n for n in held), scope
+
+
+def test_expert_counters_per_step():
+    """A model with expert layers keeps, per step, its pairs on held
+    experts and the dispatch buffer rows that carried them, each summed
+    over layers and microbatches.  The smoke DeepSeek-V2-Lite block (4 of 8
+    experts held, top 3) gives one chunk: n·min(k, held) rows a layer."""
+    from repro.launch.train import model_config
+    from repro.models.moe import buffer_chunks
+    job = TrainJob(**dict(TINY, arch="deepseek-v2-lite-16b", steps=2,
+                          schedule="constant", base_global_batch=4,
+                          max_global_batch=4))
+    hist = run_training(job)
+    cfg = model_config(job)
+    m = cfg.moe
+    n = job.base_micro_batch * job.seq_len
+    rows, chunks = buffer_chunks(n, m.top_k, m.held, m.num_experts)
+    assert (rows, chunks) == (n * 3, 1)
+    calls = (cfg.num_layers - m.first_dense) * job.base_accum
+    assert hist[spans.EXPERT_BUFFER_ROWS] == [float(rows * calls)] * 2
+    assert all(0 < r <= b for r, b in zip(hist[spans.EXPERT_ROWS],
+                                         hist[spans.EXPERT_BUFFER_ROWS]))

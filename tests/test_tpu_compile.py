@@ -210,9 +210,15 @@ def test_deepseek_v2_lite_step_fits_one_v5e(topo, as_tpu):
         assert kernel in text, kernel
     assert not re.search(r"f32\[\d+,16,4096,4096\]", text)
     # gate, up and down: forward, recomputed and the input's gradient
-    # (`gmm`), the weights' gradient (`tgmm`)
-    assert len(re.findall(r"%gmm(?:\.\d+)? = ", text)) == 9
-    assert len(re.findall(r"%tgmm(?:\.\d+)? = ", text)) == 3
+    # (`gmm`), the weights' gradient (`tgmm`), for each chunk of the
+    # dispatch buffer (`moe.buffer_chunks`: 4 of 12,288 rows here)
+    from repro.configs import get_config
+    from repro.models.moe import buffer_chunks
+    m = get_config(program["arch"]).replace(**overrides).moe
+    _, chunks = buffer_chunks(micro * traffic["seq_len"], m.top_k, m.held,
+                              m.num_experts)
+    assert len(re.findall(r"%gmm(?:\.\d+)? = ", text)) == 9 * chunks
+    assert len(re.findall(r"%tgmm(?:\.\d+)? = ", text)) == 3 * chunks
     assert "ragged-dot" not in text
     weights = compiled.memory_analysis().argument_size_in_bytes / 3
     assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
